@@ -67,7 +67,9 @@ print(f"  greedy continuation (request 0): {seq}")
 
 print()
 print("=" * 64)
-print("3) KERNEL — Pallas moe_gmm (interpret) vs jnp oracle")
+on_tpu = jax.default_backend() == "tpu"
+print(f"3) KERNEL — Pallas moe_gmm ({'compiled' if on_tpu else 'interpret'})"
+      " vs jnp oracle")
 print("=" * 64)
 from repro.kernels import ref
 from repro.kernels.moe_gmm import moe_gmm_pallas
@@ -77,7 +79,7 @@ x = jax.random.normal(ks[0], (e, t, d), jnp.float32) * 0.3
 wg = jax.random.normal(ks[1], (e, d, f), jnp.float32) * 0.1
 wu = jax.random.normal(ks[2], (e, d, f), jnp.float32) * 0.1
 wd = jax.random.normal(ks[3], (e, f, d), jnp.float32) * 0.1
-got = moe_gmm_pallas(x, wg, wu, wd, interpret=True)
+got = moe_gmm_pallas(x, wg, wu, wd, interpret=not on_tpu)
 want = ref.moe_gmm_ref(x, wg, wu, wd)
 err = float(jnp.max(jnp.abs(got - want)))
 print(f"  [E={e}, T={t}, D={d}, F={f}]  max |pallas - ref| = {err:.2e}")

@@ -52,8 +52,10 @@ def main():
     out = eng.run()
     dt = time.time() - t0
     total_tokens = sum(len(v) for v in out.values())
+    dev = jax.devices()[0]
     print(f"completed {len(out)} requests, {total_tokens} tokens "
-          f"in {dt:.1f}s ({total_tokens / dt:.1f} tok/s on CPU)")
+          f"in {dt:.1f}s ({total_tokens / dt:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind})")
     for rid in rids[:4]:
         print(f"  req {rid}: prompt={prompts[rid]} -> {out[rid]}")
     if len(rids) > 4:

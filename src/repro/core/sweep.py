@@ -103,9 +103,6 @@ def _resolve_backend(backend: Optional[str]) -> str:
     if b not in BACKENDS:
         raise ValueError(f"unknown sweep backend {b!r}; "
                          f"expected one of {BACKENDS}")
-    if b == "jax":
-        from repro.core import sweep_jax
-        sweep_jax.require_jax()
     return b
 
 

@@ -31,46 +31,26 @@ jitted device program:
                   see the skew code
 
 Numerics contract (docs/sweep_engine.md): every kernel runs under
-`jax.experimental.enable_x64` (float64, same associations as the NumPy
+`jax.enable_x64` (float64, same associations as the NumPy
 path wherever practical), and the NumPy engine remains the 1e-9-vs-scalar
 REFERENCE — this backend is held to <= 1e-6 relative against it
 (tests/test_sweep_jax.py; in practice the agreement is ~1e-12). All public
 functions take and return NumPy arrays; JAX never leaks to callers.
-
-JAX is an install-time dependency of the repo, but this module still
-degrades gracefully: `HAVE_JAX` is False when import fails and
-`sweep`'s backend resolution raises a clear error instead of crashing at
-first use.
 """
 from __future__ import annotations
 
 from functools import lru_cache
 from typing import Dict, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64, lax
 
 from repro.core import optable
 from repro.core.compute_model import (EFF_MEMORY, GEMM_SMALL_TOKENS,
                                       T_LAUNCH)
 from repro.core.overlap import LANES, MAX_STAGGER
-
-try:  # pragma: no cover - exercised implicitly by every jax test
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except Exception:  # pragma: no cover
-    jax = jnp = lax = enable_x64 = None
-    HAVE_JAX = False
-
-
-def require_jax() -> None:
-    if not HAVE_JAX:
-        raise RuntimeError(
-            "sweep backend 'jax' requested but jax failed to import; "
-            "install jax or use backend='numpy'")
-
 
 # keys of the per-op leaves every kernel scans over (leading axis n_ops)
 _PER_OP_KEYS = ("kind", "stage_scale", "eff", "eff_small", "flop_row",
@@ -190,11 +170,7 @@ def _op_factors(op, peak, hbm, rows, bpd, ctx, knee):
     return comp, comm, op["kind"] == optable.KIND_COMPUTE
 
 
-def _jit(fn):
-    return jax.jit(fn) if HAVE_JAX else fn
-
-
-@_jit
+@jax.jit
 def _seq_kernel(lw, rows, bpd, ctx):
     """(t_compute, t_comm) sums over the op axis, each (n_cl, n_sc, n_b).
     A `lax.scan` accumulation over the factored per-op forms: nothing of
@@ -246,7 +222,7 @@ def _op_factors_skew(op, peak, hbm, rows, bpd, ctx, knee):
     return comp, comm, op["kind"] == optable.KIND_COMPUTE
 
 
-@_jit
+@jax.jit
 def _seq_kernel_skew(lw, rows, bpd, ctx):
     """`_seq_kernel` for skewed grids: same scan, scenario-carrying comm
     accumulator (n_cl, n_sc, n_b)."""
@@ -269,7 +245,7 @@ def _seq_kernel_skew(lw, rows, bpd, ctx):
     return tc[lw["xpu_idx"]], tm
 
 
-@_jit
+@jax.jit
 def _dur_kernel_skew(lw, rows, bpd, ctx):
     """`_dur_kernel` for skewed grids (per-op durations for the DBO
     makespan, full (n_ops, n_cl, n_sc, n_b))."""
@@ -287,7 +263,7 @@ def _dur_kernel_skew(lw, rows, bpd, ctx):
     return dur
 
 
-@_jit
+@jax.jit
 def _dur_kernel(lw, rows, bpd, ctx):
     """Per-op duration tensor (n_ops, n_cl, n_sc, n_b) — the DBO makespan
     needs the individual rows (each op is gathered once per merged-order
@@ -307,7 +283,7 @@ def _dur_kernel(lw, rows, bpd, ctx):
     return dur
 
 
-@_jit
+@jax.jit
 def _makespan_kernel(lane, dur_a, dur_b, ks, mbs):
     """Best-stagger makespan of the fixed-order three-lane schedule —
     `sweep._lane_makespan` as a (max,+) `lax.scan` over the merged order,
@@ -342,7 +318,7 @@ def _makespan_kernel(lane, dur_a, dur_b, ks, mbs):
 # jitted kernels (prefill chunks: sizes/offsets aligned vectors)
 # ---------------------------------------------------------------------------
 
-@_jit
+@jax.jit
 def _prefill_dur_kernel(lw, rows, bpd, chunk, ctx):
     """Per-op per-chunk durations (n_ops, n_chunks) of one chunk schedule
     on one cluster — the jnp twin of `sweep._prefill_chunk_durations`
@@ -379,7 +355,6 @@ def prefill_chunk_times(ptable, cluster, batch_global: int,
     """Jitted `sweep._prefill_chunk_times`: per-chunk prefill iteration
     times, (n_chunks,). dbo=True takes best-of(no-overlap, three-lane DBO
     over the causal ceil/floor half-chunk split) per chunk."""
-    require_jax()
     lw = lower_grid(ptable, [cluster])
     s_arr = np.asarray(sizes, np.float64)
     o_arr = np.asarray(offsets, np.float64)
@@ -415,7 +390,6 @@ class JaxGridEngine:
 
     def __init__(self, table, clusters, scenarios,
                  batches: np.ndarray, half: np.ndarray, load=None):
-        require_jax()
         self.table = table
         self.lw = lower_grid(table, clusters)
         self.skew = load is not None

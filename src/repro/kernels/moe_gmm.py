@@ -12,8 +12,13 @@ feeds the MXU with 128-aligned tiles:
   over the expert's weights, so expert weights stream HBM->VMEM exactly once
   per token-tile.
 
-VMEM per step (bt=128, bf=256, D=4096, bf16):
-  x 1 MiB + w_gate/w_up/w_down 3*2 MiB + acc f32 2 MiB  ~= 9 MiB  (< 16 MiB)
+VMEM per step, bf16: the pipeline double-buffers every input and output
+tile, and the f32 accumulator is single:
+  2 * (x bt*D + w_gate/w_up/w_down 3*D*bf + out bt*D) * 2 B + acc bt*D*4 B
+At bt=128, bf=256 that is 9 MiB for D=2048 (olmoe), 18 MiB for D=4096 and
+31.5 MiB for D=7168 (deepseek-v3). The TPU compiler's default scoped VMEM
+limit on v5e is 16 MiB, so it refuses D=4096 and D=7168 at block_f=256
+(RESOURCE_EXHAUSTED ... vmem); D=7168 is refused at block_f=128 too.
 """
 from __future__ import annotations
 

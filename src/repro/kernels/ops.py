@@ -1,47 +1,21 @@
-"""Jit'd dispatching wrappers around the Pallas kernels.
+"""Dispatching wrapper around the grouped expert matmul kernel.
 
-The model code calls these; they pick the Pallas TPU kernel on TPU backends
-and the pure-jnp oracle elsewhere (CPU smoke tests, 512-device dry-run).
-Set REPRO_FORCE_IMPL={pallas,pallas_interpret,ref} to override.
+The model calls `moe_gmm`. It runs the Pallas TPU kernel on a TPU backend
+and the pure-jnp oracle on any other (the CPU tests, the dry-run grid).
+The choice rests on the platform alone: the kernel pads the token and FFN
+axes to its tiles itself, so every shape the model produces runs on it.
+Interpret mode is an explicit argument of the kernel, which only the
+kernel tests pass.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 from repro.kernels import ref as kref
 
 
-def _impl() -> str:
-    forced = os.environ.get("REPRO_FORCE_IMPL", "")
-    if forced:
-        return forced
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
 def moe_gmm(x, w_gate, w_up, w_down):
-    impl = _impl()
-    if impl == "ref":
+    if jax.default_backend() != "tpu":
         return kref.moe_gmm_ref(x, w_gate, w_up, w_down)
     from repro.kernels.moe_gmm import moe_gmm_pallas
-    t, f = x.shape[1], w_gate.shape[-1]
-    if t % 8 or f % 8:        # shapes too small/ragged for the kernel tiling
-        return kref.moe_gmm_ref(x, w_gate, w_up, w_down)
-    return moe_gmm_pallas(
-        x, w_gate, w_up, w_down,
-        block_t=min(128, t), block_f=min(256, f),
-        interpret=(impl == "pallas_interpret"))
-
-
-def flash_decode(q, k, v, length):
-    impl = _impl()
-    if impl == "ref":
-        return kref.flash_decode_ref(q, k, v, length)
-    from repro.kernels.flash_decode import flash_decode_pallas
-    s = k.shape[2]
-    if s % 8:
-        return kref.flash_decode_ref(q, k, v, length)
-    return flash_decode_pallas(
-        q, k, v, length, block_s=min(512, s),
-        interpret=(impl == "pallas_interpret"))
+    return moe_gmm_pallas(x, w_gate, w_up, w_down)

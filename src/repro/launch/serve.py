@@ -92,9 +92,11 @@ def main():
 
     seqs = np.concatenate(out, axis=1)
     thpt = args.batch * (args.new_tokens - 1) / dt
+    dev = jax.devices()[0]
     print(f"prefill {args.batch}x{args.prompt_len} in {t_prefill:.2f}s; "
           f"decode {args.new_tokens - 1} steps in {dt:.2f}s "
-          f"({thpt:.1f} tok/s on CPU)")
+          f"({thpt:.1f} tok/s on {len(jax.devices())} x {dev.platform} "
+          f"{dev.device_kind})")
     for b in range(min(args.batch, 3)):
         print(f"  seq {b}: {seqs[b].tolist()}")
     print(f"plan: ffn_2d={dec_plan.ffn_2d} a2a_fp8={dec_plan.a2a_fp8} "

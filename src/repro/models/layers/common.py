@@ -155,7 +155,17 @@ def init_embedding(cfg, plan: ShardingPlan, key):
 
 def embed(params, tokens, cfg, plan: ShardingPlan, dist: Dist):
     """tokens: [B, S_loc] int32 -> [B, S_loc, D]. Vocab-sharded table:
-    each rank embeds the ids it owns, psum over the vocab axis."""
+    each rank embeds the ids it owns, psum over the vocab axis.
+
+    Where the sequence is sharded over the vocab axis too (train and
+    prefill plans), the ranks of that axis hold different tokens: each
+    embeds the whole sequence, and one reduce-scatter sums the vocab
+    partials and hands each rank its own sequence chunk."""
+    seq_over_vocab = (plan.seq_axis is not None
+                      and plan.seq_axis == plan.vocab_axis
+                      and dist.size(plan.seq_axis) > 1)
+    if seq_over_vocab:
+        tokens = dist.all_gather(tokens, plan.seq_axis, dim=1)
     table = params["table"]
     v_loc = table.shape[0]
     r = dist.index(plan.vocab_axis)
@@ -164,6 +174,8 @@ def embed(params, tokens, cfg, plan: ShardingPlan, dist: Dist):
     safe = jnp.clip(local, 0, v_loc - 1)
     out = jnp.take(table, safe, axis=0)
     out = jnp.where(in_range[..., None], out, jnp.zeros_like(out))
+    if seq_over_vocab:
+        return dist.reduce_scatter(out, plan.vocab_axis, dim=1)
     return dist.psum(out, plan.vocab_axis)
 
 

@@ -163,6 +163,15 @@ def train_loss(params, batch, cfg: ModelConfig, plan: ShardingPlan,
 def prefill(params, batch, cfg: ModelConfig, plan: ShardingPlan, dist: Dist,
             *, unroll: bool = False):
     """Returns (next_token [B, 1], caches). Fills the KV/state caches."""
+    logits, caches = prefill_logits(params, batch, cfg, plan, dist,
+                                    unroll=unroll)
+    return common.greedy_sample(logits, cfg, plan, dist), caches
+
+
+def prefill_logits(params, batch, cfg: ModelConfig, plan: ShardingPlan,
+                   dist: Dist, *, unroll: bool = False):
+    """`prefill` before sampling: (logits [B, 1, V_loc] f32 of the last
+    position, vocab-sharded, caches)."""
     x = _embed_inputs(params, batch, cfg, plan, dist)
     enc_out = None
     if cfg.is_encoder_decoder:
@@ -180,9 +189,7 @@ def prefill(params, batch, cfg: ModelConfig, plan: ShardingPlan, dist: Dist,
         r = dist.index(seq_ax)
         contrib = jnp.where(r == n_seq - 1, last, jnp.zeros_like(last))
         last = dist.psum(contrib, seq_ax)
-    logits = common.lm_logits(params["embed"], last, cfg, plan, dist)
-    token = common.greedy_sample(logits, cfg, plan, dist)
-    return token, caches
+    return common.lm_logits(params["embed"], last, cfg, plan, dist), caches
 
 
 def decode_step(params, caches, tokens, pos, cfg: ModelConfig,
@@ -190,14 +197,22 @@ def decode_step(params, caches, tokens, pos, cfg: ModelConfig,
                 unroll: bool = False):
     """One serving step: tokens [B, 1] -> (next token [B, 1], new caches).
     pos: scalar int32 position of `tokens` in the sequence."""
+    logits, caches = decode_logits(params, caches, tokens, pos, cfg, plan,
+                                   dist, enc_len=enc_len, unroll=unroll)
+    return common.greedy_sample(logits, cfg, plan, dist), caches
+
+
+def decode_logits(params, caches, tokens, pos, cfg: ModelConfig,
+                  plan: ShardingPlan, dist: Dist, *, enc_len: int = 0,
+                  unroll: bool = False):
+    """`decode_step` before sampling: (logits [B, 1, V_loc] f32,
+    vocab-sharded, new caches)."""
     x = common.embed(params["embed"], tokens, cfg, plan, dist)
     x, caches, _ = tf.apply_stack(params["stack"], x, cfg, plan, dist,
                                   mode="decode", caches=caches, pos=pos,
                                   enc_len=enc_len, unroll=unroll)
     x = common.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = common.lm_logits(params["embed"], x, cfg, plan, dist)
-    token = common.greedy_sample(logits, cfg, plan, dist)
-    return token, caches
+    return common.lm_logits(params["embed"], x, cfg, plan, dist), caches
 
 
 # ---------------------------------------------------------------------------

@@ -101,11 +101,9 @@ class Engine:
     # prefill
     # ------------------------------------------------------------------
 
-    def _prefill_one(self, prompt: List[int]):
-        """Prefill a single request; returns (first generated token [1,1],
-        capacity-padded cache with batch dim 1)."""
-        L = len(prompt)
-        assert 0 < L < self.max_seq, (L, self.max_seq)
+    def _prefill_fn(self, L: int):
+        """The jitted prefill for prompt length L, built once per length;
+        called as fn(params, tokens[, frames])."""
         fn = self._prefill_cache.get(L)
         if fn is None:
             pplan = dataclasses.replace(self.plan, kind="prefill")
@@ -120,6 +118,14 @@ class Engine:
 
             fn = jax.jit(fn)
             self._prefill_cache[L] = fn
+        return fn
+
+    def _prefill_one(self, prompt: List[int]):
+        """Prefill a single request; returns (first generated token [1,1],
+        capacity-padded cache with batch dim 1)."""
+        L = len(prompt)
+        assert 0 < L < self.max_seq, (L, self.max_seq)
+        fn = self._prefill_fn(L)
         tokens = jnp.asarray(prompt, jnp.int32)[None, :]
         frames = None
         if self.cfg.frontend == "audio_frames":
@@ -128,9 +134,6 @@ class Engine:
         tok, sub = fn(self.params, tokens, frames) \
             if frames is not None else fn(self.params, tokens)
         sub = kvcache.pad_to_capacity(self.cfg, sub, L, self.max_seq)
-        if self.cfg.is_encoder_decoder:
-            # cross cache capacity == enc len L -> pad to engine capacity
-            pass
         return tok, sub
 
     # ------------------------------------------------------------------
@@ -142,21 +145,24 @@ class Engine:
         enc_len = self.max_seq if cfg.is_encoder_decoder else 0
         bdims = kvcache.batch_dim_tree(self.caches)
 
-        def one(caches, tok, pos):
+        def one(params, caches, tok, pos):
             # re-add the batch dim vmap stripped (per-leaf position)
             c1 = jax.tree.map(lambda x, d: jnp.expand_dims(x, d),
                               caches, bdims)
             t1 = tok.reshape(1, 1)
-            nt, nc = M.decode_step(self.params, c1, t1, pos, cfg, plan,
+            nt, nc = M.decode_step(params, c1, t1, pos, cfg, plan,
                                    dist, enc_len=enc_len)
             return nt[0, 0], jax.tree.map(lambda x, d: jnp.squeeze(x, d),
                                           nc, bdims)
 
-        def wave(caches, toks, pos):
-            return jax.vmap(one, in_axes=(bdims, 0, 0),
-                            out_axes=(0, bdims))(caches, toks[:, 0], pos)
+        def wave(params, caches, toks, pos):
+            # the weights are an argument, not a closed-over constant, so
+            # the compiled program does not embed a copy of them
+            return jax.vmap(one, in_axes=(None, bdims, 0, 0),
+                            out_axes=(0, bdims))(params, caches,
+                                                 toks[:, 0], pos)
 
-        return jax.jit(wave, donate_argnums=(0,))
+        return jax.jit(wave, donate_argnums=(1,))
 
     def step(self) -> int:
         """One engine iteration: admit waiting requests, advance all live
@@ -165,8 +171,8 @@ class Engine:
         n_live = sum(self.live)
         if n_live == 0:
             return 0
-        toks, self.caches = self._decode_wave(self.caches, self.last_tok,
-                                              self.pos)
+        toks, self.caches = self._decode_wave(self.params, self.caches,
+                                              self.last_tok, self.pos)
         self.last_tok = toks[:, None]
         self.pos = self.pos + 1
         for slot, req in enumerate(self.slots):

@@ -3,9 +3,15 @@
 Mesh axes:
   pod    — cross-pod data parallelism only (gradient all-reduce traffic;
            the paper's principle: keep A2A inside the high-bandwidth domain)
-  data   — batch DP; FSDP shard axis in training; the decode A2A (EP) axis
+  data   — batch DP; FSDP shard axis in training
   model  — the "scale-up domain": TP / sequence-parallel activations /
-           train+prefill EP axis / decode KV-sequence sharding
+           the EP (expert all-to-all) axis / decode KV-sequence sharding
+
+The expert layout is one per mesh: every kind of step shards the experts
+over `model`, so a server holds one copy of the weights for both prefill
+and decode. Decode tokens are replicated over `model` (sharded over
+`data`), so each `model` rank dispatches the same tokens and the expert
+FFN computes each of them once per rank.
 
 Attention modes:
   head_tp    — q heads sharded over `model` (requires heads % tp == 0 and
@@ -130,13 +136,8 @@ def make_plan(cfg: ModelConfig, shape: ShapeCell,
             a2a_fp8=a2a_fp8,
         )
 
-    # decode: batch over DP axes; KV sequence over model; EP A2A over data.
+    # decode: batch over DP axes; KV sequence and experts over model.
     batch_axes = dp_axes if shape.global_batch % dp == 0 else None
-    ep_axis = None
-    if cfg.moe:
-        # faithful A2A path when tokens are batch-sharded; degenerate
-        # replicated-token fallback (B=1 long-context) routes over model.
-        ep_axis = "data" if (batch_axes and "data" in batch_axes) else "model"
     # ffn_2d requires tokens batch-sharded over data and d_ff/vocab
     # divisible by the full (data x model) product
     use_2d = (ffn_2d and batch_axes and "data" in batch_axes
@@ -146,7 +147,7 @@ def make_plan(cfg: ModelConfig, shape: ShapeCell,
         batch_axes=batch_axes,
         seq_axis=None,
         tp_axis="model",
-        ep_axis=ep_axis,
+        ep_axis="model" if cfg.moe else None,
         kv_axis="model",
         attn_mode=attn_mode,
         fsdp_axis=None,

@@ -99,14 +99,8 @@ B, cap = 8, 32
 mesh = make_mesh((2, 4), ("data", "model"))
 shape = ShapeCell("d", cap, B, "decode")
 
-from repro.models.layers import common
-from repro.models import transformer as tf
 def logits_of(params, caches, tokens, pos, plan, dist):
-    x = common.embed(params["embed"], tokens, cfg, plan, dist)
-    x, nc, _ = tf.apply_stack(params["stack"], x, cfg, plan, dist,
-                              mode="decode", caches=caches, pos=pos)
-    x = common.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    return common.lm_logits(params["embed"], x, cfg, plan, dist)
+    return M.decode_logits(params, caches, tokens, pos, cfg, plan, dist)[0]
 
 plan0 = null_plan("decode")
 params0, _ = M.init_model(cfg, plan0, jax.random.PRNGKey(0))
@@ -143,6 +137,49 @@ print(json.dumps({{"max_diff": max_diff, "flips_ok": flips_ok}}))
 """)
     assert res["max_diff"] < 0.05, res
     assert res["flips_ok"], res
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "starcoder2-3b"])
+def test_prefill_matches_single_device(arch):
+    """Sequence-sharded prefill (tokens split over `model`, which also
+    shards the vocab) fills the same KV cache and gives the same last-
+    position logits as the single-device prefill."""
+    res = run_sub(COMMON + f"""
+arch = {arch!r}
+cfg = cfg_for(arch, num_heads=4, num_kv_heads=2)
+B, L = 4, 16
+mesh = make_mesh((2, 4), ("data", "model"))
+shape = ShapeCell("p", L, B, "prefill")
+tok = jax.random.randint(jax.random.PRNGKey(1), (B, L), 0, cfg.vocab_size)
+params0, _ = M.init_model(cfg, null_plan("prefill"), jax.random.PRNGKey(0))
+l0, c0 = M.prefill_logits(params0, {{"tokens": tok}}, cfg,
+                          null_plan("prefill"), NullDist())
+
+plan = make_plan(cfg, shape, ("data", "model"), (2, 4), fsdp=False)
+pspecs = S.abstract_model(cfg, plan)[1]
+_, cspecs = S.abstract_cache(cfg, plan, B, L)
+from repro.sharding.dist import Dist
+dist = Dist(dict(data=2, model=4))
+def step(p, t):
+    lg, c = M.prefill_logits(p, {{"tokens": t}}, cfg, plan, dist)
+    return dist.all_gather(lg, plan.vocab_axis, dim=-1), c
+tok_spec = P(plan.batch_axes, plan.seq_axis)
+f = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(pspecs, tok_spec),
+            out_specs=(P(plan.batch_axes, None, None), cspecs),
+            check_vma=False))
+with mesh:
+    l1, c1 = f(put(params0, pspecs, mesh),
+               jax.device_put(tok, NamedSharding(mesh, tok_spec)))
+f32 = lambda x: np.asarray(x, np.float32)
+cache_diff = max(float(np.abs(f32(a) - f32(b)).max())
+                 for a, b in zip(jax.tree.leaves(c0), jax.tree.leaves(c1)))
+l0f, l1f = f32(l0[:, 0]), f32(l1[:, 0])
+print(json.dumps({{"logit_diff": float(np.abs(l0f - l1f).max()),
+                   "logit_scale": float(np.abs(l0f[np.isfinite(l0f)]).max()),
+                   "cache_diff": cache_diff}}))
+""")
+    assert res["cache_diff"] < 0.05, res
+    assert res["logit_diff"] < 0.05, res
 
 
 def test_elastic_checkpoint_across_meshes(tmp_path):
