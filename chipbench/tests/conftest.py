@@ -1,0 +1,11 @@
+"""The benchmark's tests run on the CPU, at tiny sizes:
+`python -m pytest chipbench/tests` from the repository's root."""
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
