@@ -256,6 +256,7 @@ def _local_kv_slice(cfg, plan: ShardingPlan, dist: Dist):
 # train / prefill self-attention
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def attention_fwd(params, x, cfg, plan: ShardingPlan, dist: Dist, *,
                   causal: bool = True, window: int = 0,
                   make_cache: bool = False):
@@ -362,6 +363,7 @@ def _window_cache_from_prefill(k_c, v_c, window, s_loc, plan, dist):
 # decode self-attention (KV cache)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def attention_decode(params, x, cache, pos, cfg, plan: ShardingPlan,
                      dist: Dist, *, window: int = 0):
     """x: [B, 1, D] (replicated over tp); cache k/v: [B, KV, S_loc, hd]

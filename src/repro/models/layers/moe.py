@@ -135,32 +135,37 @@ def moe_ffn(params, x, cfg, plan: ShardingPlan, dist: Dist,
     e_pad = params["router"].shape[-1]
     cap = capacity(n_tok, m.experts_per_token, e_pad, m.capacity_factor)
 
-    logits = xt.astype(jnp.float32) @ params["router"]
-    gates, idx, probs = route(logits, m.experts_per_token, m.num_experts)
-    slot, keep = slot_assignment(idx, e_pad, cap)
+    with jax.named_scope("router"):
+        logits = xt.astype(jnp.float32) @ params["router"]
+        gates, idx, probs = route(logits, m.experts_per_token, m.num_experts)
+        slot, keep = slot_assignment(idx, e_pad, cap)
 
-    # scatter tokens into [E*C, D] expert buffers
-    flat_idx = (idx * cap + jnp.clip(slot, 0, cap - 1)).reshape(-1)  # [T*k]
-    contrib = (xt[:, None, :] * keep[..., None].astype(xt.dtype))
-    x_e = jnp.zeros((e_pad * cap, d), xt.dtype).at[flat_idx].add(
-        contrib.reshape(-1, d))
-    x_e = x_e.reshape(e_pad, cap, d)
+    with jax.named_scope("dispatch"):
+        # scatter tokens into [E*C, D] expert buffers; flat_idx [T*k]
+        flat_idx = (idx * cap + jnp.clip(slot, 0, cap - 1)).reshape(-1)
+        contrib = (xt[:, None, :] * keep[..., None].astype(xt.dtype))
+        x_e = jnp.zeros((e_pad * cap, d), xt.dtype).at[flat_idx].add(
+            contrib.reshape(-1, d))
+        x_e = x_e.reshape(e_pad, cap, d)
+        if ep > 1:
+            if plan.a2a_fp8:
+                x_e = fp8_dispatch_a2a(x_e, ep_ax, dist)
+            else:
+                x_e = dist.all_to_all(x_e, ep_ax, split_dim=0, concat_dim=1)
+            # -> [E_loc, ep*C, D]: rows for MY experts from every EP rank
 
-    if ep > 1:
-        if plan.a2a_fp8:
-            x_e = fp8_dispatch_a2a(x_e, ep_ax, dist)
-        else:
-            x_e = dist.all_to_all(x_e, ep_ax, split_dim=0, concat_dim=1)
-        # -> [E_loc, ep*C, D]: rows for MY experts from every EP rank
-    h = kops.moe_gmm(x_e, params["w_gate"], params["w_up"], params["w_down"])
-    if ep > 1:
-        h = dist.all_to_all(h, ep_ax, split_dim=1, concat_dim=0)    # [E, C, D]
+    with jax.named_scope("experts"):
+        h = kops.moe_gmm(x_e, params["w_gate"], params["w_up"],
+                         params["w_down"])
 
-    # gather back and combine with gates
-    h_flat = h.reshape(e_pad * cap, d)
-    picked = jnp.take(h_flat, flat_idx, axis=0).reshape(n_tok, -1, d)
-    w = (gates * keep.astype(gates.dtype)).astype(h.dtype)
-    y = jnp.einsum("tk,tkd->td", w, picked).reshape(B, t, d)
+    with jax.named_scope("combine"):
+        if ep > 1:                                          # [E, C, D]
+            h = dist.all_to_all(h, ep_ax, split_dim=1, concat_dim=0)
+        # gather back and combine with gates
+        h_flat = h.reshape(e_pad * cap, d)
+        picked = jnp.take(h_flat, flat_idx, axis=0).reshape(n_tok, -1, d)
+        w = (gates * keep.astype(gates.dtype)).astype(h.dtype)
+        y = jnp.einsum("tk,tkd->td", w, picked).reshape(B, t, d)
 
     if m.num_shared_experts:
         xs = x

@@ -8,8 +8,24 @@ as requests hit EOS or their token budget, making room for waiting
 requests — the standard continuous-batching loop.
 
 Static shapes throughout: the decode wave compiles once; prefill compiles
-once per distinct prompt length (production systems bucket lengths; the
-engine exposes `prefill_buckets` for that).
+once per distinct prompt length, and the `engine.prefill` span's
+`new_program` attr marks the call that builds (compiles, or loads from
+the cache) the program for a new length.
+
+Each step is timed where its work happens, by host spans
+(`repro.serving.spans`), with their attrs:
+
+  engine.step      one `step` (live, reads)
+  engine.admit     the admission loop
+  engine.prefill   one prompt, to its first token ready (rid, length,
+                   new_program)
+  engine.insert    the prefill into its slot, and its first token read
+                   (rid)
+  engine.wave      the decode wave, to its tokens ready (live)
+  engine.readback  from the wave's tokens ready to the end of the step:
+                   positions, per-slot reads, retirements (reads)
+
+`reads` counts the device-to-host reads, each made through `_to_host`.
 """
 from __future__ import annotations
 
@@ -23,6 +39,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.serving import kvcache
+from repro.serving.spans import span
 from repro.sharding.dist import Dist, NullDist
 from repro.sharding.plans import ShardingPlan, null_plan
 
@@ -61,6 +78,7 @@ class Engine:
         self.queue: deque[Request] = deque()
         self.finished: Dict[int, Request] = {}
         self._rid = 0
+        self.host_reads = 0            # device-to-host reads so far
         self._decode_wave = self._build_decode_wave()
         self._prefill_cache: Dict[int, Any] = {}
 
@@ -74,19 +92,31 @@ class Engine:
         self.queue.append(Request(rid, list(prompt), max_new_tokens))
         return rid
 
+    def _to_host(self, x) -> int:
+        """Every device-to-host read of the engine goes through here, so
+        the spans' `reads` counts are exact."""
+        self.host_reads += 1
+        return int(x)
+
     def _admit(self):
-        while self.queue and not all(self.live):
-            slot = self.live.index(False)
-            req = self.queue.popleft()
-            tok0, sub = self._prefill_one(req.prompt)
-            self.caches = kvcache.insert_slot(self.caches, sub, slot)
-            self.pos = self.pos.at[slot].set(len(req.prompt))
-            self.last_tok = self.last_tok.at[slot].set(tok0[0])
-            req.generated = [int(tok0[0, 0])]
-            self.slots[slot] = req
-            self.live[slot] = True
-            if req.generated[-1] == self.eos_id:
-                self._retire(slot)
+        with span("engine.admit"):
+            while self.queue and not all(self.live):
+                slot = self.live.index(False)
+                req = self.queue.popleft()
+                L = len(req.prompt)
+                with span("engine.prefill", rid=req.rid, length=L,
+                          new_program=int(L not in self._prefill_cache)):
+                    tok0, sub = self._prefill_one(req.prompt)
+                    tok0.block_until_ready()
+                with span("engine.insert", rid=req.rid):
+                    self.caches = kvcache.insert_slot(self.caches, sub, slot)
+                    self.pos = self.pos.at[slot].set(L)
+                    self.last_tok = self.last_tok.at[slot].set(tok0[0])
+                    req.generated = [self._to_host(tok0[0, 0])]
+                self.slots[slot] = req
+                self.live[slot] = True
+                if req.generated[-1] == self.eos_id:
+                    self._retire(slot)
 
     def _retire(self, slot: int):
         req = self.slots[slot]
@@ -167,24 +197,36 @@ class Engine:
     def step(self) -> int:
         """One engine iteration: admit waiting requests, advance all live
         slots one token. Returns number of live slots stepped."""
-        self._admit()
-        n_live = sum(self.live)
-        if n_live == 0:
-            return 0
-        toks, self.caches = self._decode_wave(self.params, self.caches,
-                                              self.last_tok, self.pos)
-        self.last_tok = toks[:, None]
-        self.pos = self.pos + 1
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            t = int(toks[slot])
-            req.generated.append(t)
-            ntok = len(req.generated) - 1       # first came from prefill
-            if (t == self.eos_id or ntok >= req.max_new_tokens
-                    or int(self.pos[slot]) >= self.max_seq - 1):
-                self._retire(slot)
+        first = self.host_reads
+        with span("engine.step") as step_attrs:
+            self._admit()
+            n_live = step_attrs["live"] = sum(self.live)
+            if n_live:
+                self._advance(n_live)
+            step_attrs["reads"] = self.host_reads - first
         return n_live
+
+    def _advance(self, n_live: int):
+        """The decode wave over every live slot, then its tokens and
+        positions read back and finished requests retired."""
+        with span("engine.wave", live=n_live):
+            toks, self.caches = self._decode_wave(self.params, self.caches,
+                                                  self.last_tok, self.pos)
+            toks.block_until_ready()
+        first = self.host_reads
+        with span("engine.readback") as attrs:
+            self.last_tok = toks[:, None]
+            self.pos = self.pos + 1
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                t = self._to_host(toks[slot])
+                req.generated.append(t)
+                ntok = len(req.generated) - 1   # first came from prefill
+                if (t == self.eos_id or ntok >= req.max_new_tokens
+                        or self._to_host(self.pos[slot]) >= self.max_seq - 1):
+                    self._retire(slot)
+            attrs["reads"] = self.host_reads - first
 
     def run(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         """Drive until every submitted request completes."""
