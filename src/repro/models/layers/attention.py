@@ -37,8 +37,8 @@ from repro.sharding.plans import ShardingPlan
 NEG_INF = -1e30
 
 # REPRO_ATTN_F32=1 restores the pre-optimization attention numerics
-# (materialized f32 K/V copies + full-cache select on decode update) —
-# the §Perf iteration-1 BASELINE (EXPERIMENTS.md).
+# (materialized f32 K/V copies) — the §Perf iteration-1 BASELINE
+# (EXPERIMENTS.md).
 ATTN_F32_BASELINE = os.environ.get("REPRO_ATTN_F32", "") == "1"
 
 
@@ -115,8 +115,9 @@ def attn_chunk_lse(q, k, v, *, pos_k, max_pos):
     """Single-chunk decode attention returning unnormalized (o, m, lsum) for the
     cross-rank log-sum-exp combine.
 
-    q: [B, H, hd]; k, v: [B, KH, S_loc, hd]; pos_k: [S_loc] absolute
-    positions; max_pos: highest attendable position (inclusive).
+    q: [B, H, hd]; k, v: [B, KH, S_loc, hd]; pos_k: [S_loc] or [B, S_loc]
+    absolute positions; max_pos: highest attendable position (inclusive),
+    scalar or [B].
     Returns o: [B, H, hd] f32 (sum of e^{s-m} v), m: [B, H], lsum: [B, H].
     """
     B, H, hd = q.shape
@@ -131,7 +132,7 @@ def attn_chunk_lse(q, k, v, *, pos_k, max_pos):
         qr, k, v = (t.astype(jnp.float32) for t in (qr, k, v))
     s = jnp.einsum("bhgd,bhsd->bhgs", qr, k,
                    preferred_element_type=jnp.float32) * scale
-    mask = pos_k[None, None, None, :] <= max_pos
+    mask = (pos_k <= jnp.expand_dims(max_pos, -1))[..., None, None, :]
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
@@ -368,31 +369,33 @@ def attention_decode(params, x, cache, pos, cfg, plan: ShardingPlan,
                      dist: Dist, *, window: int = 0):
     """x: [B, 1, D] (replicated over tp); cache k/v: [B, KV, S_loc, hd]
     (seq-sharded over plan.kv_axis; ring buffer [B, KV, W, hd] if window).
-    pos: scalar int32, position of the incoming token. Returns (y, cache)."""
-    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    pos: [B] int32, position of each row's incoming token. Returns
+    (y, cache)."""
+    hd = cfg.head_dim
     B = x.shape[0]
     xt = x[:, 0]                                              # [B, D]
     tp = dist.size(plan.tp_axis)
+    rows = jnp.arange(B)
 
     q = (xt @ params["w_q"]).reshape(B, -1, hd)
     if plan.attn_mode == "head_tp" and tp > 1:
         q = dist.all_gather(q, plan.tp_axis, dim=1)           # [B, H, hd]
-    q = apply_rope(q[:, None], jnp.full((1,), pos), cfg.rope_theta)[:, 0]
+    q = apply_rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
 
     k_new = jnp.einsum("bd,dkh->bkh", xt, params["w_k"])
     v_new = jnp.einsum("bd,dkh->bkh", xt, params["w_v"])
-    k_new = apply_rope(k_new[:, None], jnp.full((1,), pos),
-                       cfg.rope_theta)[:, 0]
+    k_new = apply_rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
 
+    # Each row's token is scattered into its own row alone: rebuilding the
+    # cache with a where() made XLA copy the whole cache per layer (§Perf
+    # iteration 1).
     if window:
-        slot = pos % window
-        k_c = jax.lax.dynamic_update_slice(
-            cache["k"], k_new[:, :, None, :], (0, 0, slot, 0))
-        v_c = jax.lax.dynamic_update_slice(
-            cache["v"], v_new[:, :, None, :], (0, 0, slot, 0))
         w = cache["k"].shape[2]
+        slot = pos % w
+        k_c = cache["k"].at[rows, :, slot].set(k_new)
+        v_c = cache["v"].at[rows, :, slot].set(v_new)
         slots = jnp.arange(w)
-        slot_pos = pos - jnp.mod(pos - slots, w)              # abs pos per slot
+        slot_pos = pos[:, None] - jnp.mod(pos[:, None] - slots, w)  # [B, W]
         # unwritten slots (early decode, pos < window) -> mask out
         slot_pos = jnp.where(slot_pos < 0, jnp.int32(2 ** 30), slot_pos)
         o, m, lsum = attn_chunk_lse(q, k_c, v_c, pos_k=slot_pos, max_pos=pos)
@@ -402,36 +405,14 @@ def attention_decode(params, x, cache, pos, cfg, plan: ShardingPlan,
         kv_ax = plan.kv_axis
         r = dist.index(kv_ax)
         local = pos - r * s_loc
-        in_range = (local >= 0) & (local < s_loc)
-        lc = jnp.clip(local, 0, s_loc - 1)
-        # non-owner ranks write the OLD value back at the clamped slot:
-        # the select stays slice-sized (a full-cache where() forced XLA to
-        # copy/convert the whole cache per layer — §Perf iteration 1)
-        if ATTN_F32_BASELINE:
-            k_up = jax.lax.dynamic_update_slice(
-                cache["k"], k_new[:, :, None, :], (0, 0, lc, 0))
-            v_up = jax.lax.dynamic_update_slice(
-                cache["v"], v_new[:, :, None, :], (0, 0, lc, 0))
-            k_c = jnp.where(in_range, k_up, cache["k"])
-            v_c = jnp.where(in_range, v_up, cache["v"])
-        else:
-            B_, KV_ = k_new.shape[0], k_new.shape[1]
-            old_k = jax.lax.dynamic_slice(cache["k"], (0, 0, lc, 0),
-                                          (B_, KV_, 1, cache["k"].shape[3]))
-            old_v = jax.lax.dynamic_slice(cache["v"], (0, 0, lc, 0),
-                                          (B_, KV_, 1, cache["v"].shape[3]))
-            u_k = jnp.where(in_range, k_new[:, :, None, :], old_k)
-            u_v = jnp.where(in_range, v_new[:, :, None, :], old_v)
-            k_c = jax.lax.dynamic_update_slice(cache["k"], u_k,
-                                               (0, 0, lc, 0))
-            v_c = jax.lax.dynamic_update_slice(cache["v"], u_v,
-                                               (0, 0, lc, 0))
+        # only the rank that owns a row's position writes it; the others,
+        # and a dead slot whose position ran past the cache, drop the write
+        local = jnp.where((local >= 0) & (local < s_loc), local, s_loc)
+        k_c = cache["k"].at[rows, :, local].set(k_new, mode="drop")
+        v_c = cache["v"].at[rows, :, local].set(v_new, mode="drop")
         pos_k = r * s_loc + jnp.arange(s_loc)
         o, m, lsum = attn_chunk_lse(q, k_c, v_c, pos_k=pos_k, max_pos=pos)
         o = lse_combine(o, m, lsum, kv_ax, dist)
-        cache = {"k": k_c, "v": v_c}
-        y = _decode_out_proj(o, params, plan, dist, B)
-        return y, cache
 
     cache = {"k": k_c, "v": v_c}
     y = _decode_out_proj(o, params, plan, dist, B)

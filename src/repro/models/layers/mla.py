@@ -102,13 +102,16 @@ def mla_fwd(params, x, cfg, plan: ShardingPlan, dist: Dist, *,
 
 def mla_decode(params, x, cache, pos, cfg, plan: ShardingPlan, dist: Dist):
     """x: [B, 1, D]; cache: c_kv [B, S, r], k_rope [B, S, rp] (replicated
-    over model, batch over data)."""
-    H, hd = cfg.num_heads, cfg.head_dim
+    over model, batch over data); pos: [B] int32, position of each row's
+    incoming token."""
+    hd = cfg.head_dim
     B = x.shape[0]
-    q_n, q_r, c_new, kr_new = _qkv(params, x, cfg,
-                                   jnp.full((1,), pos))
-    c_kv = jax.lax.dynamic_update_slice(cache["c_kv"], c_new, (0, pos, 0))
-    k_rope = jax.lax.dynamic_update_slice(cache["k_rope"], kr_new, (0, pos, 0))
+    q_n, q_r, c_new, kr_new = _qkv(params, x, cfg, pos[:, None])
+    # each row writes its own position; a dead slot whose position ran
+    # past the cache drops the write
+    rows = jnp.arange(B)
+    c_kv = cache["c_kv"].at[rows, pos].set(c_new[:, 0], mode="drop")
+    k_rope = cache["k_rope"].at[rows, pos].set(kr_new[:, 0], mode="drop")
     k, v = _decompress(params, c_kv, cfg)                    # [B, S, H, hd]
     S = k.shape[1]
     scale = 1.0 / math.sqrt(hd + q_r.shape[-1])
@@ -116,8 +119,8 @@ def mla_decode(params, x, cache, pos, cfg, plan: ShardingPlan, dist: Dist):
                     k.astype(jnp.float32))
          + jnp.einsum("bhr,bsr->bhs", q_r[:, 0].astype(jnp.float32),
                       k_rope.astype(jnp.float32))) * scale
-    valid = jnp.arange(S) <= pos
-    s = jnp.where(valid[None, None], s, NEG_INF)
+    valid = jnp.arange(S) <= pos[:, None]                      # [B, S]
+    s = jnp.where(valid[:, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhs,bshd->bhd", p, v.astype(jnp.float32))
     y = o.reshape(B, -1).astype(x.dtype) @ params["w_o"]

@@ -123,9 +123,16 @@ def aux_load_balance_loss(probs, idx, n_real: int):
 
 
 def moe_ffn(params, x, cfg, plan: ShardingPlan, dist: Dist,
-            *, collect_aux: bool = False):
+            *, decode: bool = False, collect_aux: bool = False):
     """x: [B, T_loc, D] local token slice on each EP rank.
-    Returns (y, aux_loss)."""
+    Returns (y, aux_loss).
+
+    Capacity: the tokens of a prompt (train, prefill) are one group, given
+    `capacity_factor` of the even share each. In decode (x [B, 1, D], one
+    token of each of B requests) every token is a group of its own, as if
+    decoded alone: each expert gets B rows, which hold every routing
+    decision (a token picks an expert at most once), so none is dropped
+    and no row's result depends on another's."""
     m = cfg.moe
     B, t, d = x.shape
     xt = x.reshape(B * t, d)
@@ -133,7 +140,8 @@ def moe_ffn(params, x, cfg, plan: ShardingPlan, dist: Dist,
     ep_ax = plan.ep_axis
     ep = dist.size(ep_ax)
     e_pad = params["router"].shape[-1]
-    cap = capacity(n_tok, m.experts_per_token, e_pad, m.capacity_factor)
+    cap = n_tok if decode else capacity(n_tok, m.experts_per_token, e_pad,
+                                        m.capacity_factor)
 
     with jax.named_scope("router"):
         logits = xt.astype(jnp.float32) @ params["router"]

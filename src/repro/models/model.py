@@ -196,7 +196,8 @@ def decode_step(params, caches, tokens, pos, cfg: ModelConfig,
                 plan: ShardingPlan, dist: Dist, *, enc_len: int = 0,
                 unroll: bool = False):
     """One serving step: tokens [B, 1] -> (next token [B, 1], new caches).
-    pos: scalar int32 position of `tokens` in the sequence."""
+    pos: int32 position of each row's token in its sequence, [B]; a scalar
+    gives every row the same position."""
     logits, caches = decode_logits(params, caches, tokens, pos, cfg, plan,
                                    dist, enc_len=enc_len, unroll=unroll)
     return common.greedy_sample(logits, cfg, plan, dist), caches

@@ -71,8 +71,12 @@ def init_layer(spec: LayerSpec, cfg: ModelConfig, plan: ShardingPlan, key,
 def apply_layer(spec: LayerSpec, p, x, cfg, plan: ShardingPlan, dist: Dist, *,
                 mode: str, cache=None, pos=None, enc_len=None, enc_out=None,
                 collect_aux: bool = False):
-    """mode: train | prefill | decode. Returns (x, new_cache, aux)."""
+    """mode: train | prefill | decode. Returns (x, new_cache, aux).
+    pos (decode): int32 position of each row's token, [B], or a scalar
+    that every row shares."""
     new_cache: Dict[str, Any] = {}
+    if mode == "decode":
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), x.shape[:1])
     aux = jnp.float32(0)
     window = cfg.sliding_window if spec.mixer == "attn_local" else 0
 
@@ -145,6 +149,7 @@ def apply_layer(spec: LayerSpec, p, x, cfg, plan: ShardingPlan, dist: Dist, *,
         h = common.dense_ffn(p["ffn"], h, plan, dist)
     elif spec.ffn == "moe":
         h, aux = moe_mod.moe_ffn(p["ffn"], h, cfg, plan, dist,
+                                 decode=(mode == "decode"),
                                  collect_aux=collect_aux)
     else:
         h = jnp.zeros_like(x)
@@ -211,6 +216,7 @@ def apply_stack(params, x, cfg: ModelConfig, plan: ShardingPlan, dist: Dist,
                 unroll: bool = False):
     """caches: {"periods": tuple_of_stacked, "rem": tuple} (decode) or None
     (train/prefill — prefill CREATES caches). Returns (x, new_caches|None, aux).
+    pos (decode): [B] per-row positions, or a scalar shared by every row.
 
     unroll=True unrolls the period scan (XLA cost_analysis counts a scan
     body once, so exact roofline accounting needs the unrolled program;

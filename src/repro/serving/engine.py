@@ -3,9 +3,9 @@
 A fixed pool of `max_batch` slots over a fixed-capacity cache. Requests are
 admitted into free slots (prefill at the request's length, cache padded to
 capacity and scattered into the slot); every decode wave advances ALL live
-slots one token with per-slot positions (vmapped decode step). Slots free
-as requests hit EOS or their token budget, making room for waiting
-requests — the standard continuous-batching loop.
+slots one token in one batched decode step, each slot at its own position.
+Slots free as requests hit EOS or their token budget, making room for
+waiting requests — the standard continuous-batching loop.
 
 Static shapes throughout: the decode wave compiles once; prefill compiles
 once per distinct prompt length, and the `engine.prefill` span's
@@ -167,30 +167,21 @@ class Engine:
         return tok, sub
 
     # ------------------------------------------------------------------
-    # decode wave (per-slot positions via vmap)
+    # decode wave (one batched step, per-slot positions)
     # ------------------------------------------------------------------
 
     def _build_decode_wave(self):
+        """wave(params, caches, toks [B, 1], pos [B]) -> (toks [B], caches),
+        jitted with the caches donated."""
         cfg, plan, dist = self.cfg, self.plan, self.dist
         enc_len = self.max_seq if cfg.is_encoder_decoder else 0
-        bdims = kvcache.batch_dim_tree(self.caches)
-
-        def one(params, caches, tok, pos):
-            # re-add the batch dim vmap stripped (per-leaf position)
-            c1 = jax.tree.map(lambda x, d: jnp.expand_dims(x, d),
-                              caches, bdims)
-            t1 = tok.reshape(1, 1)
-            nt, nc = M.decode_step(params, c1, t1, pos, cfg, plan,
-                                   dist, enc_len=enc_len)
-            return nt[0, 0], jax.tree.map(lambda x, d: jnp.squeeze(x, d),
-                                          nc, bdims)
 
         def wave(params, caches, toks, pos):
             # the weights are an argument, not a closed-over constant, so
             # the compiled program does not embed a copy of them
-            return jax.vmap(one, in_axes=(None, bdims, 0, 0),
-                            out_axes=(0, bdims))(params, caches,
-                                                 toks[:, 0], pos)
+            nt, nc = M.decode_step(params, caches, toks, pos, cfg, plan,
+                                   dist, enc_len=enc_len)
+            return nt[:, 0], nc
 
         return jax.jit(wave, donate_argnums=(1,))
 

@@ -11,6 +11,7 @@ The topology is described inside a fixture and never while a module is
 imported: only one process at a time may load the TPU library, and the
 test workers all import this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -24,7 +25,8 @@ from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.models.layers.moe import capacity
 
 PROMPT_TOKENS = 64          # one 64-token prefill sets the expert capacity
-DECODE_SLOTS = 4            # the engine's decode wave vmaps over its slots
+DECODE_SLOTS = 8            # the engine's decode wave: one token a slot, and
+                            # each expert holds one row a slot
 
 
 @pytest.fixture(scope="module")
@@ -63,16 +65,43 @@ def test_moe_gmm_compiles_at_prefill_width(arch, one_chip):
 
 @pytest.mark.parametrize("arch", ["olmoe-1b-7b", "granite-moe-3b-a800m"])
 def test_moe_gmm_compiles_in_decode_wave_form(arch, one_chip):
-    """Capacity 1 per expert, vmapped over the engine's slots."""
+    """One row per slot in every expert: x [E, DECODE_SLOTS, D]."""
     cfg = get_arch(arch)
     m = cfg.moe
-    t = capacity(1, m.experts_per_token, m.num_experts, m.capacity_factor)
-    assert t == 1
-    x = _struct((DECODE_SLOTS, m.num_experts, t, cfg.d_model), one_chip)
-    wave = jax.vmap(moe_gmm_pallas, in_axes=(0, None, None, None))
-    compiled = jax.jit(wave).lower(
+    x = _struct((m.num_experts, DECODE_SLOTS, cfg.d_model), one_chip)
+    compiled = jax.jit(moe_gmm_pallas).lower(
         x, *_expert_weights(cfg, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "granite-moe-3b-a800m"])
+def test_decode_wave_calls_moe_gmm_once_over_all_slots(arch, one_chip,
+                                                       monkeypatch):
+    """The engine's decode wave (two layers, the Pallas kernel as on a
+    TPU) steps every slot in one batched call: each layer's kernel takes
+    x [E, DECODE_SLOTS, D], with no slot axis of its own."""
+    from repro.kernels import ops
+    from repro.models import model as M
+    from repro.serving.engine import Engine
+    from repro.sharding.plans import null_plan
+
+    monkeypatch.setattr(ops, "moe_gmm", moe_gmm_pallas)
+    cfg = dataclasses.replace(get_arch(arch), num_layers=2)
+    like = jax.eval_shape(lambda k: M.init_model(cfg, null_plan("decode"),
+                                                 k)[0], jax.random.PRNGKey(0))
+    eng = Engine(cfg, like, max_batch=DECODE_SLOTS, max_seq=64, eos_id=-1)
+
+    def on_chip(t):
+        return jax.tree.map(lambda a: _struct(a.shape, one_chip, a.dtype), t)
+
+    text = eng._decode_wave.lower(
+        on_chip(like), on_chip(eng.caches),
+        _struct((DECODE_SLOTS, 1), one_chip, jnp.int32),
+        _struct((DECODE_SLOTS,), one_chip, jnp.int32)).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    x_e = f"bf16[{cfg.moe.num_experts},{DECODE_SLOTS},{cfg.d_model}]"
+    assert kernels and all(x_e in line for line in kernels), kernels
 
 
 def test_flash_decode_compiles_at_olmoe_width(one_chip):
