@@ -23,9 +23,12 @@ Each step is timed where its work happens, by host spans
                    (rid)
   engine.wave      the decode wave, to its tokens ready (live)
   engine.readback  from the wave's tokens ready to the end of the step:
-                   positions, per-slot reads, retirements (reads)
+                   one read of the token vector, positions advanced on
+                   the host, retirements (reads)
 
 `reads` counts the device-to-host reads, each made through `_to_host`.
+Slot positions live on the host (`pos`): set at admission, one added to
+every row after each wave, and uploaded with the wave's call.
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
@@ -71,7 +75,7 @@ class Engine:
 
         enc = max_seq if cfg.is_encoder_decoder else 0
         self.caches, _ = M.init_cache(cfg, self.plan, max_batch, max_seq, enc)
-        self.pos = jnp.zeros((max_batch,), jnp.int32)
+        self.pos = np.zeros((max_batch,), np.int32)
         self.last_tok = jnp.zeros((max_batch, 1), jnp.int32)
         self.live = [False] * max_batch
         self.slots: List[Optional[Request]] = [None] * max_batch
@@ -92,11 +96,13 @@ class Engine:
         self.queue.append(Request(rid, list(prompt), max_new_tokens))
         return rid
 
-    def _to_host(self, x) -> int:
+    def _to_host(self, x) -> int | List[int]:
         """Every device-to-host read of the engine goes through here, so
-        the spans' `reads` counts are exact."""
+        the spans' `reads` counts are exact. A scalar comes back as an
+        `int`, an array as a list of its values; either is one transfer
+        and counts once."""
         self.host_reads += 1
-        return int(x)
+        return int(x) if x.ndim == 0 else jax.device_get(x).tolist()
 
     def _admit(self):
         with span("engine.admit"):
@@ -110,7 +116,7 @@ class Engine:
                     tok0.block_until_ready()
                 with span("engine.insert", rid=req.rid):
                     self.caches = kvcache.insert_slot(self.caches, sub, slot)
-                    self.pos = self.pos.at[slot].set(L)
+                    self.pos[slot] = L
                     self.last_tok = self.last_tok.at[slot].set(tok0[0])
                     req.generated = [self._to_host(tok0[0, 0])]
                 self.slots[slot] = req
@@ -198,24 +204,28 @@ class Engine:
         return n_live
 
     def _advance(self, n_live: int):
-        """The decode wave over every live slot, then its tokens and
-        positions read back and finished requests retired."""
+        """The decode wave over every live slot, then its token vector read
+        back in one transfer, every position advanced on the host, and
+        finished requests retired."""
         with span("engine.wave", live=n_live):
             toks, self.caches = self._decode_wave(self.params, self.caches,
                                                   self.last_tok, self.pos)
+            toks.copy_to_host_async()
             toks.block_until_ready()
         first = self.host_reads
         with span("engine.readback") as attrs:
             self.last_tok = toks[:, None]
+            # a new array, so the one handed to the wave is never written
             self.pos = self.pos + 1
+            host_toks = self._to_host(toks)
             for slot, req in enumerate(self.slots):
                 if req is None:
                     continue
-                t = self._to_host(toks[slot])
+                t = host_toks[slot]
                 req.generated.append(t)
                 ntok = len(req.generated) - 1   # first came from prefill
                 if (t == self.eos_id or ntok >= req.max_new_tokens
-                        or self._to_host(self.pos[slot]) >= self.max_seq - 1):
+                        or self.pos[slot] >= self.max_seq - 1):
                     self._retire(slot)
             attrs["reads"] = self.host_reads - first
 

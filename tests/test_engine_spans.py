@@ -71,11 +71,11 @@ def test_spans_nest_as_documented(model):
 
 @pytest.mark.parametrize("retire_by", ["budget", "positions", "eos"])
 def test_reads_equal_an_independent_count(model, retire_by, monkeypatch):
-    """Every `int` taken of a device array is a read, counted here apart
-    from the engine's own count. A slot reads its token and then, unless
-    that token ends the request (EOS or its budget), its position: two
-    reads per live slot per wave, plus one per admission (its first
-    token), less one for each request a wave's token retired."""
+    """Every `int` or `__array__` taken of a device array is a read,
+    counted here apart from the engine's own count. The engine reads one
+    token at each admission (its first) and the whole token vector once a
+    wave, whatever the live count: slot positions stay on the host, so
+    retiring by position reads nothing."""
     kw = {"budget": {"new_tokens": 4},
           "positions": {"new_tokens": 40, "max_seq": 12},
           "eos": {"new_tokens": 6}}[retire_by]
@@ -103,14 +103,17 @@ def test_reads_equal_an_independent_count(model, retire_by, monkeypatch):
     eng._retire = retire
     counted = []
     array_type = type(jax.numpy.zeros(()))
-    to_int = array_type.__int__
 
-    def counting(x):
-        counted.append(1)
-        return to_int(x)
+    def counting(method):
+        def read(x, *args, **kwargs):
+            counted.append(method.__name__)
+            return method(x, *args, **kwargs)
+        return read
 
     first = newest_id()
-    monkeypatch.setattr(array_type, "__int__", counting)
+    for name in ("__int__", "__array__"):
+        monkeypatch.setattr(array_type, name,
+                            counting(getattr(array_type, name)))
     eng.run()
     monkeypatch.undo()
     recs = [r for r in S.records() if r.id > first]
@@ -123,12 +126,11 @@ def test_reads_equal_an_independent_count(model, retire_by, monkeypatch):
         assert not by_token
     else:
         assert by_token[retire_by] > 0
-    slot_waves = sum(s.attrs["live"] for s in steps)
-    assert eng.host_reads == \
-        2 * slot_waves + len(rids) - sum(by_token.values())
-    readback = sum(r.attrs["reads"] for r in recs
-                   if r.name == "engine.readback")
-    assert readback == eng.host_reads - len(rids)
+    waves = [r for r in recs if r.name == "engine.wave"]
+    readbacks = [r for r in recs if r.name == "engine.readback"]
+    assert eng.host_reads == len(waves) + len(rids)
+    assert len(readbacks) == len(waves)
+    assert all(r.attrs["reads"] == 1 for r in readbacks)
 
 
 def test_new_program_only_on_first_prefill_of_each_length(model):

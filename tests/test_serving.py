@@ -84,6 +84,49 @@ def test_engine_isolation():
     assert both[ra] == alone
 
 
+REFILL_REQUESTS = [([3, 1, 4, 1, 5], 2), ([9, 2, 6], 4),
+                   ([5, 3, 5, 8, 9, 7], 1), ([2, 7, 1, 8], 40),
+                   ([8, 2, 8], 3), ([1, 6, 1, 8, 0, 3, 3], 5)]
+REFILL_MAX_SEQ = 10
+
+
+@pytest.fixture(scope="module")
+def refill_reference():
+    """olmoe (reduced) and each of REFILL_REQUESTS's greedy tokens, ended
+    by its budget or at the last position of the cache."""
+    cfg, params = make_model("olmoe-1b-7b")
+    refs = []
+    for prompt, budget in REFILL_REQUESTS:
+        n = 1 + min(budget, REFILL_MAX_SEQ - 1 - len(prompt))
+        ref = greedy_reference(cfg, params, jnp.asarray([prompt], jnp.int32),
+                               n, REFILL_MAX_SEQ)
+        refs.append([int(t) for t in ref[0]])
+    return cfg, params, refs
+
+
+@pytest.mark.parametrize("max_batch", [1, 2, 4])
+def test_engine_refills_slots_with_host_positions(refill_reference,
+                                                  max_batch):
+    """More requests than slots, with mixed budgets and two that run to
+    the end of the cache: slots refill mid-run, every request is served
+    the greedy reference's tokens and ends where it does, and each live
+    slot's host-held position is its prompt length plus its decoded
+    tokens."""
+    cfg, params, refs = refill_reference
+    eng = Engine(cfg, params, max_batch=max_batch, max_seq=REFILL_MAX_SEQ,
+                 eos_id=-1)
+    rids = [eng.submit(p, max_new_tokens=b) for p, b in REFILL_REQUESTS]
+    while eng.queue or any(eng.live):
+        eng.step()
+        for slot, req in enumerate(eng.slots):
+            if req is not None:
+                assert eng.pos[slot] == \
+                    len(req.prompt) + len(req.generated) - 1
+    assert set(eng.finished) == set(rids)
+    for rid, ref in zip(rids, refs):
+        assert eng.finished[rid].generated == ref
+
+
 # ---------------------------------------------------------------------------
 # DBO step
 # ---------------------------------------------------------------------------
