@@ -56,7 +56,7 @@ def run(verbose: bool = True):
     results["claims"] = {
         # (a) 150+DBO approaches 450 at TPOT >= 60ms (paper: 'nearly
         # matches'; our anomaly-free DBO schedule reaches ~0.81-0.87 —
-        # ratio reported below, delta discussed in EXPERIMENTS.md)
+        # ratio reported below)
         "dbo_150_matches_450_at_60ms":
             at("dbo", 150, i60) > 0.80 * at("dbo", 450, i60),
         "dbo_150_over_450_ratio_60ms":

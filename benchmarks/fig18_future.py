@@ -62,7 +62,7 @@ def run(verbose: bool = True):
         # Blackwell: in relaxed/long-context scenarios full-mesh reaches
         # (most of) scale-up's performance at the 900 GB/s provision.
         # (Our model places the SHORT-context 40ms boundary one generation
-        # earlier than the paper — same mechanism, see EXPERIMENTS.md.)
+        # earlier than the paper — same mechanism.)
         "blackwell_fullmesh_parity_long_ctx":
             curve_at("Blackwell", "tpot40ms_ctx4096", "fullmesh", 900e9)
             >= 0.85 * curve_at("Blackwell", "tpot40ms_ctx4096", "scale-up",

@@ -66,8 +66,7 @@ def run(verbose: bool = True):
     hi, lo = results["fig5"]
     # DBO must close most of the BW-induced latency gap (paper: 'a
     # lower-cost network can match the performance of expensive networks';
-    # our anomaly-free schedule hides ~75% of the exposed comm — see
-    # EXPERIMENTS.md for the delta discussion)
+    # our anomaly-free schedule hides ~75% of the exposed comm)
     gap_no = lo["t_noopt_ms"] - hi["t_noopt_ms"]
     gap_dbo = lo["t_dbo_ms"] - hi["t_dbo_ms"]
     results["claims"] = {

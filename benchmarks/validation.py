@@ -11,8 +11,8 @@ compute model against the SAME public reference points the paper used:
 
 We report our model's TPOT at that operating point and the relative error.
 The paper's profiled model achieves <7.5%; our unprofiled roofline model is
-expected to land within ~2x (documented in EXPERIMENTS.md; all topology
-COMPARISONS are ratios, which cancel first-order efficiency error)."""
+expected to land within ~2x (all topology COMPARISONS are ratios, which
+cancel first-order efficiency error)."""
 from __future__ import annotations
 
 from benchmarks.common import save, table

@@ -1,26 +1,19 @@
 """End-to-end serving driver (the paper's workload kind): batched requests
-through the continuous-batching engine, with and without speculative
-decoding, on a reduced MoE model.
+through the continuous-batching engine on a reduced MoE model.
 
   PYTHONPATH=src python examples/serve_moe.py [--arch olmoe-1b-7b]
-      [--requests 12] [--max-batch 4] [--sd]
+      [--requests 12] [--max-batch 4]
 
-Prints per-request completions, slot reuse, and tokens/s; with --sd also
-runs the speculative decoder and reports acceptance + the greedy-equality
-check (SD must never change outputs).
+Prints per-request completions, slot reuse, and tokens/s.
 """
 import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced_config
 from repro.models import model as M
-from repro.serving import kvcache
 from repro.serving.engine import Engine
-from repro.serving.specdec import SDDecoder
-from repro.sharding.dist import NullDist
 from repro.sharding.plans import null_plan
 
 
@@ -31,8 +24,6 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=96)
     ap.add_argument("--new-tokens", type=int, default=12)
-    ap.add_argument("--sd", action="store_true",
-                    help="also run the speculative decoder")
     args = ap.parse_args()
 
     cfg = reduced_config(get_arch(args.arch))
@@ -60,24 +51,6 @@ def main():
         print(f"  req {rid}: prompt={prompts[rid]} -> {out[rid]}")
     if len(rids) > 4:
         print(f"  ... ({len(rids) - 4} more)")
-
-    if args.sd:
-        print("\nspeculative decoding (spec_m=4, untrained Medusa heads):")
-        prompt = jnp.asarray([prompts[0]], jnp.int32)
-        tok, caches = M.prefill(params, {"tokens": prompt}, cfg,
-                                null_plan("prefill"), NullDist())
-        caches = kvcache.pad_to_capacity(cfg, caches, prompt.shape[1],
-                                         args.max_seq)
-        dec = SDDecoder(cfg, params, spec_m=4)
-        toks, _, stats = dec.generate(caches, tok, prompt.shape[1],
-                                      args.new_tokens)
-        got = [int(tok[0, 0])] + [int(t) for t in toks[0]]
-        want = out[rids[0]][:len(got)]
-        print(f"  SD output:     {got}")
-        print(f"  greedy output: {want}")
-        print(f"  identical: {got == want}  "
-              f"mean accepted/iter: {stats['mean_accepted']:.2f} "
-              f"({stats['iterations']} iterations)")
 
 
 if __name__ == "__main__":

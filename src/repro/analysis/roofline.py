@@ -103,7 +103,7 @@ def from_dryrun(res: Dict) -> Optional[Roofline]:
 
 
 def what_would_help(r: Roofline) -> str:
-    """One-sentence suggestion for the dominant term (EXPERIMENTS.md)."""
+    """One-sentence suggestion for the dominant term."""
     b = r.bottleneck
     if b == "collective":
         return ("reduce collective volume: shrink FSDP all-gather via "

@@ -216,8 +216,8 @@ SHAPES = {
 
 
 def cell_applicable(cfg: ModelConfig, shape: ShapeCell) -> Tuple[bool, str]:
-    """long_500k needs sub-quadratic attention; skip pure full-attention archs
-    (documented in DESIGN.md section 6)."""
+    """long_500k needs sub-quadratic attention; skip pure full-attention
+    archs."""
     if shape.name == "long_500k" and cfg.full_attention_only:
         return False, "pure full-attention arch: 512k KV/step is architecturally inapplicable"
     return True, ""

@@ -1,7 +1,7 @@
 """Per-layer compute-time estimation (paper section 3.2.3).
 
 The paper profiles kernels on a real H100 (Vidur-style). Without GPU access
-(DESIGN.md section 7), we use a roofline-with-efficiency model:
+we use a roofline-with-efficiency model:
 
   t = max(flops / (peak * eff_c(op)),  bytes / (hbm_bw * eff_m)) + t_launch
 

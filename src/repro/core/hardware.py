@@ -2,7 +2,7 @@
 
 The paper bases its model on NVIDIA Hopper and projects Blackwell/Rubin with
 the Table 5 multipliers. We add TPU v5e — the execution target of the JAX
-half of this repo — parameterizing the same methodology (DESIGN.md section 3).
+half of this repo — parameterizing the same methodology.
 
 Layer: leaf data (no dependencies inside core); every engine reads the
 same spec objects, so there is nothing parity-sensitive here.

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: grouped expert SwiGLU matmul (the MoE FFN hot spot).
 
 The paper's expert computation (dense per-expert FFN over the A2A'd token
-buffers) is the dominant MoE compute. TPU adaptation (DESIGN.md section 3):
+buffers) is the dominant MoE compute. TPU adaptation:
 instead of a CUTLASS grouped GEMM over ragged token groups, we use the
 static-capacity layout [E, T, D] produced by the dispatch scatter, tiled so
 each (expert, token-tile, f-tile) step keeps its working set in VMEM and

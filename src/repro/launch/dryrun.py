@@ -4,7 +4,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 
 # Multi-pod dry-run: AOT lower + compile every (arch x shape) cell on the
 # production mesh, record memory/cost analysis + collective bytes for the
-# roofline (EXPERIMENTS.md section Dry-run / section Roofline).
+# roofline (benchmarks/roofline.py).
 #
 # Usage:
 #   PYTHONPATH=src python -m repro.launch.dryrun --arch starcoder2-3b --shape train_4k

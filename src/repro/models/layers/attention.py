@@ -1,6 +1,6 @@
 """Attention layers.
 
-Three execution paths (see DESIGN.md section 4):
+Three execution paths:
 
 train / prefill (tokens seq-sharded over `model`):
   head_tp     AG(x over seq) -> q on local head shard, K/V on the single KV
@@ -23,7 +23,6 @@ Prefill writes the cache in exactly the decode layout:
 from __future__ import annotations
 
 import math
-import os
 from typing import Tuple
 
 import jax
@@ -36,15 +35,11 @@ from repro.sharding.plans import ShardingPlan
 
 NEG_INF = -1e30
 
-# REPRO_ATTN_F32=1 restores the pre-optimization attention numerics
-# (materialized f32 K/V copies) — the §Perf iteration-1 BASELINE
-# (EXPERIMENTS.md).
-ATTN_F32_BASELINE = os.environ.get("REPRO_ATTN_F32", "") == "1"
-
 
 # ---------------------------------------------------------------------------
-# chunked flash attention core (pure jnp; the Pallas kernel in
-# repro.kernels.flash_decode covers the TPU hot path, validated vs this)
+# attention cores in plain jnp: chunked flash attention for train and
+# prefill, one chunk with its log-sum-exp for decode (attn_chunk_lse, held
+# to kernels.ref.decode_attention_ref by tests/test_kernels.py)
 # ---------------------------------------------------------------------------
 
 def flash_attn(q, k, v, *, causal: bool, window: int = 0,
@@ -79,12 +74,9 @@ def flash_attn(q, k, v, *, causal: bool, window: int = 0,
         ci, kci, vci = inp
         pos_k = kv_offset + ci * ck + jnp.arange(ck)
         # bf16-native matmuls with f32 accumulation (MXU-style): never
-        # materialize an f32 copy of K/V — that doubled HBM traffic and
-        # dominated the dry-run memory roofline (EXPERIMENTS.md §Perf)
-        qq = qr
-        if ATTN_F32_BASELINE:
-            qq, kci, vci = (t.astype(jnp.float32) for t in (qq, kci, vci))
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", qq, kci,
+        # materialize an f32 copy of K/V, which would double the HBM
+        # traffic
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", qr, kci,
                        preferred_element_type=jnp.float32) * scale
         mask = (pos_k[None, :] < kv_len)
         if causal:
@@ -124,12 +116,9 @@ def attn_chunk_lse(q, k, v, *, pos_k, max_pos):
     KH = k.shape[1]
     g = H // KH
     scale = 1.0 / math.sqrt(hd)
-    # bf16-native score/value matmuls with f32 accumulation: reading the KV
-    # cache at bf16 width (instead of materializing an f32 copy) is the
-    # decode memory-roofline fix of EXPERIMENTS.md §Perf iteration 1
+    # bf16-native score/value matmuls with f32 accumulation: the KV cache
+    # is read at its own width, never as an f32 copy
     qr = q.reshape(B, KH, g, hd).astype(k.dtype)
-    if ATTN_F32_BASELINE:
-        qr, k, v = (t.astype(jnp.float32) for t in (qr, k, v))
     s = jnp.einsum("bhgd,bhsd->bhgs", qr, k,
                    preferred_element_type=jnp.float32) * scale
     mask = (pos_k <= jnp.expand_dims(max_pos, -1))[..., None, None, :]
@@ -155,7 +144,7 @@ def lse_combine(o, m, lsum, axis, dist: Dist):
 
 
 def ring_attention(q, k, v, *, seq_ax, dist: Dist, causal: bool = True):
-    """Ring attention over a sequence-sharded KV (§Perf iteration 3).
+    """Ring attention over a sequence-sharded KV.
 
     q: [B, Sq_loc, H_loc, hd] (local seq chunk, local head shard)
     k, v: [B, Sk_loc, KH_loc, hd] (local seq chunk of the matching KV heads)
@@ -285,9 +274,9 @@ def attention_fwd(params, x, cfg, plan: ShardingPlan, dist: Dist, *,
 
     if plan.attn_mode == "head_tp":
         if plan.ring_attn and window == 0 and dist.size(seq_ax) > 1:
-            # ring path (§Perf iteration 3): q/k/v from the LOCAL seq
-            # chunk only; KV rotates around the seq ring — no full-seq
-            # all-gather, no full-seq reduce-scatter.
+            # ring path: q/k/v from the LOCAL seq chunk only; KV rotates
+            # around the seq ring — no full-seq all-gather, no full-seq
+            # reduce-scatter.
             q = (x @ params["w_q"]).reshape(B, s_loc, -1, hd)
             start, kv_loc = _local_kv_slice(cfg, plan, dist)
             w_k = jax.lax.dynamic_slice_in_dim(params["w_k"], start, kv_loc,
@@ -387,8 +376,7 @@ def attention_decode(params, x, cache, pos, cfg, plan: ShardingPlan,
     k_new = apply_rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
 
     # Each row's token is scattered into its own row alone: rebuilding the
-    # cache with a where() made XLA copy the whole cache per layer (§Perf
-    # iteration 1).
+    # cache with a where() made XLA copy the whole cache per layer.
     if window:
         w = cache["k"].shape[2]
         slot = pos % w

@@ -83,7 +83,7 @@ def init_dense_ffn(cfg, plan: ShardingPlan, key, d_ff: Optional[int] = None):
 
 def fp8_all_gather(x, axis, dist: Dist, dim: int):
     """All-gather with an fp8(e4m3) wire format + per-row f32 scales
-    (EXPERIMENTS.md Perf iteration 4). Halves collective bytes vs bf16 —
+    (`plan.ag_fp8`). Halves collective bytes vs bf16 —
     and pins the wire width against XLA hoisting a widening convert ahead
     of the collective (observed: f32-width gathers on the CPU lowering).
     The dequantized result returns in x.dtype."""
@@ -105,7 +105,7 @@ def fp8_all_gather(x, axis, dist: Dist, dim: int):
 def dense_ffn(params, x, plan: ShardingPlan, dist: Dist):
     """x: [B, S_loc, D] (seq-sharded) or [B, T, D] (replicated over tp).
 
-    Decode ffn_2d path (§Perf iteration 2): weights column-sharded over
+    Decode ffn_2d path: weights column-sharded over
     (data x model); the handful of decode tokens all-gathers over `data`
     (cheap: B*D bytes), every device computes with a 16x thinner weight
     shard, and the partial outputs reduce-scatter back to the batch shard.

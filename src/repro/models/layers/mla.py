@@ -3,8 +3,8 @@
 The KV cache stores only the compressed latent (kv_lora_rank + rope_head_dim
 per token, e.g. 576 for V3 vs 2*128*128 for vanilla MHA), which is why the
 paper's Fig. 10 KV-capacity analysis uses MLA. Naive (non-absorbed) decode
-decompresses K/V from the latent each step; the absorbed variant is a
-hillclimb note in EXPERIMENTS.md.
+decompresses K/V from the latent each step; the absorbed variant, which
+reads only the latent, is not written yet.
 
 Replicated-weight distribution only (deepseek-v3 is the analysis workload,
 not a dry-run grid arch); the latent cache is small enough to replicate over

@@ -40,7 +40,7 @@ def fp8_dispatch_a2a(x_e, ep_ax, dist: Dist):
     """fp8(e4m3) wire format for the dispatch all-to-all (DeepSeek-V3's
     production scheme: fp8 dispatch, bf16 combine). Per-slot scales ride
     along; the uint8 bitcast pins the 1-byte wire width against XLA's
-    convert hoisting / f8-collective promotion (§Perf iteration 5)."""
+    convert hoisting / f8-collective promotion."""
     xf = x_e.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / 448.0, 1.0)

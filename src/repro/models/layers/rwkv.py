@@ -9,7 +9,7 @@ Recurrence (per head, state s: [hd, hd]):
   s_t   = diag(w_t) s_{t-1} + k_t v_t^T
 with w_t = exp(-exp(decay_t)) data-dependent via a small LoRA.
 
-Simplifications vs the release (noted in DESIGN.md): the 5-way token-shift
+Simplifications vs the release: the 5-way token-shift
 mixing LoRA is collapsed to a single learned interpolation per stream, and
 output gating uses SiLU. The communication/compute structure — which is what
 this systems paper prices — is unchanged.

@@ -1,4 +1,4 @@
-"""Serving runtime: continuous-batching engine, KV-cache management,
-dual-batch-overlap step, speculative decoding."""
+"""Serving runtime: the continuous-batching engine (`engine`), the cache
+operations it needs to admit a request into a slot (`kvcache`), and the
+host spans it times itself with (`spans`)."""
 from repro.serving.engine import Engine, Request
-from repro.serving.specdec import SDDecoder

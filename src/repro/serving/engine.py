@@ -44,8 +44,8 @@ from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.serving import kvcache
 from repro.serving.spans import span
-from repro.sharding.dist import Dist, NullDist
-from repro.sharding.plans import ShardingPlan, null_plan
+from repro.sharding.dist import NullDist
+from repro.sharding.plans import null_plan
 
 
 @dataclasses.dataclass
@@ -58,17 +58,15 @@ class Request:
 
 
 class Engine:
-    """Single-host engine (NullDist); the sharded production path reuses the
-    same model functions under shard_map (launch.steps / launch.serve)."""
+    """One device, unsharded: the model runs under `null_plan("decode")`
+    and `NullDist()`."""
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
-                 max_seq: int = 256, eos_id: int = 0,
-                 plan: Optional[ShardingPlan] = None,
-                 dist: Optional[Dist] = None):
+                 max_seq: int = 256, eos_id: int = 0):
         self.cfg = cfg
         self.params = params
-        self.plan = plan or null_plan("decode")
-        self.dist = dist or NullDist()
+        self.plan = null_plan("decode")
+        self.dist = NullDist()
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.eos_id = eos_id

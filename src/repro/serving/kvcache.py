@@ -3,15 +3,16 @@
 Caches come from ``models.model.init_cache`` as a pytree
 ``{"periods": tuple(stacked-per-period), "rem": tuple}``. This module owns
 the structural knowledge of where the *sequence* dimension lives in each
-leaf and which leaves are *recurrent* (order-dependent state that must be
-rolled back if speculative tokens are rejected) versus *positional*
-(indexed by absolute position; stale speculative writes are masked by
-``max_pos`` and later overwritten, so rollback is free).
+leaf and which leaves are *positional* (indexed by absolute position, one
+entry per position up to the cache's capacity) versus *recurrent* (state
+of a fixed size, whatever the position). The split matters because a
+prefill at prompt length L yields positional leaves L long, which
+``pad_to_capacity`` grows to the engine's capacity, while recurrent
+leaves already have their final shape and are left as they are.
 
 Leaf classes (leaf key -> class):
   k, v (full attention)   positional  (seq dim: 2 after the batch dim)
-  k, v (sliding window)   recurrent   (ring buffer: slot aliasing breaks
-                                       the masking argument)
+  k, v (sliding window)   recurrent   (ring buffer of the window's size)
   c_kv, k_rope (MLA)      positional  (seq dim: 1)
   conv, ssm (mamba)       recurrent
   wkv, shift (rwkv)       recurrent
@@ -101,30 +102,7 @@ def insert_slot(caches, sub, slot: int, *, stacked_batch_dim: Dict = None):
         lambda path, f, o: ins(path, f, o), caches, sub)
 
 
-def batch_dim_tree(caches) -> Any:
-    """Pytree of ints: which array dim is the batch dim per leaf (1 for
-    period-stacked leaves, 0 for remainder leaves). Used as vmap axes."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: 1 if (path[0].key if hasattr(path[0], "key")
-                              else path[0]) == "periods" else 0,
-        caches)
-
-
 def memory_bytes(caches) -> int:
     return int(sum(x.size * x.dtype.itemsize
                    for x in jax.tree_util.tree_leaves(caches)))
 
-
-def select_history(cfg: ModelConfig, final_caches, history, accept_idx):
-    """Combine speculative-decode cache state: positional leaves keep the
-    FINAL state (stale writes are masked/overwritten); recurrent leaves are
-    restored from `history` (stacked per verify step, leading dim T) at
-    step `accept_idx` (the last step whose input token was accepted)."""
-    def pick(path, final, hist):
-        cls, _ = _leaf_info(cfg, path)
-        if cls == "positional":
-            return final
-        return jax.lax.dynamic_index_in_dim(hist, accept_idx, axis=0,
-                                            keepdims=False)
-
-    return jax.tree_util.tree_map_with_path(pick, final_caches, history)

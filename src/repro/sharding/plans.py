@@ -51,15 +51,15 @@ class ShardingPlan:
     kind: str                                  # train | prefill | decode
     # decode-only: dense-FFN weights sharded over (data x model) with the
     # (cheap) decode tokens all-gathered over data — 16x less weight
-    # streaming per device per step (EXPERIMENTS.md §Perf iteration 2)
+    # streaming per device per step
     ffn_2d: bool = False
     # train/prefill: ring attention instead of Megatron-SP all-gather —
-    # KV chunks rotate via collective_permute (EXPERIMENTS.md §Perf it. 3)
+    # KV chunks rotate via collective_permute
     ring_attn: bool = False
-    # fp8(e4m3) wire format for the FFN sequence all-gather (§Perf it. 4)
+    # fp8(e4m3) wire format for the FFN sequence all-gather
     ag_fp8: bool = False
     # fp8 MoE dispatch A2A (bf16 combine) — DeepSeek-V3's production wire
-    # format for the paper's central traffic (§Perf iteration 5)
+    # format for the paper's central traffic
     a2a_fp8: bool = False
 
     @property
@@ -94,7 +94,7 @@ class ShardingPlan:
 
 def head_tp_ok(cfg: ModelConfig, tp: int) -> bool:
     """Head-TP requires q heads divisible by tp and each rank's q-head group
-    to map onto exactly one KV head (see DESIGN.md section 4)."""
+    to map onto exactly one KV head."""
     if not cfg.has_attention or cfg.attn_kind == "mla":
         return False
     if cfg.num_heads % tp != 0:

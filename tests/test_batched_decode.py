@@ -28,6 +28,7 @@ CASES = {
     "sliding-window": ("gemma3-1b", False),
     "mla": ("deepseek-v3", False),
     "recurrent": ("rwkv6-1.6b", False),
+    "mamba": ("jamba-v0.1-52b", False),
     "dead-slot": ("olmoe-1b-7b", True),
 }
 

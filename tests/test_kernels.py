@@ -1,7 +1,8 @@
-"""Per-kernel validation: Pallas (interpret=True on CPU) vs the pure-jnp
-oracle, swept over shapes and dtypes. The hypothesis property tests live in
-test_kernels_props.py behind pytest.importorskip, so a missing `hypothesis`
-degrades to a skip instead of killing collection."""
+"""Per-kernel validation: Pallas (interpret=True on CPU) and the decode
+path's attention vs the pure-jnp oracles, swept over shapes and dtypes.
+The hypothesis property tests live in test_kernels_props.py behind
+pytest.importorskip, so a missing `hypothesis` degrades to a skip instead
+of killing collection."""
 
 import jax
 import jax.numpy as jnp
@@ -9,8 +10,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.moe_gmm import moe_gmm_pallas
+from repro.models.layers import attention
+from repro.sharding.dist import NullDist
 
 
 def rand(key, shape, dtype):
@@ -111,52 +113,67 @@ def test_moe_gmm_expert_independence():
 
 
 # ---------------------------------------------------------------------------
-# flash_decode: online-softmax decode attention
+# decode attention: attention.attn_chunk_lse, normalised as attention_decode
+# normalises it, against the float32 oracle
 # ---------------------------------------------------------------------------
+
+def decode_attention(q, k, v, max_pos):
+    """The decode path's attention over a whole [B, KH, S, hd] cache, each
+    row attending to positions 0..max_pos (inclusive; scalar or [B])."""
+    o, m, lsum = attention.attn_chunk_lse(
+        q, k, v, pos_k=jnp.arange(k.shape[2]), max_pos=max_pos)
+    return attention.lse_combine(o, m, lsum, None, NullDist()).astype(q.dtype)
+
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("b,h,kh,s,hd", [
     (2, 8, 8, 512, 64),      # MHA
     (2, 8, 2, 1024, 64),     # GQA 4:1
-    (1, 16, 1, 2048, 128),   # MQA, long S, two S-tiles
+    (1, 16, 1, 2048, 128),   # MQA, long S
     (4, 4, 4, 512, 32),
 ])
-def test_flash_decode_matches_ref(b, h, kh, s, hd, dtype):
+def test_decode_attention_matches_ref(b, h, kh, s, hd, dtype):
     ks = jax.random.split(jax.random.PRNGKey(b * 100 + s), 3)
     q = rand(ks[0], (b, h, hd), dtype)
     k = rand(ks[1], (b, kh, s, hd), dtype)
     v = rand(ks[2], (b, kh, s, hd), dtype)
-    length = jnp.int32(s - 3)
-    got = flash_decode_pallas(q, k, v, length, interpret=True)
-    want = ref.flash_decode_ref(q, k, v, length)
+    length = s - 3
+    got = decode_attention(q, k, v, jnp.int32(length - 1))
+    want = ref.decode_attention_ref(q, k, v, length)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOLS[dtype])
 
 
-@pytest.mark.parametrize("block_s", [128, 256, 512, 1024])
-def test_flash_decode_block_invariance(block_s):
+@pytest.mark.parametrize("lengths", [
+    (700, 700, 700, 700),    # every row the same
+    (129, 333, 700, 1000),   # every row different
+    (700, 1, 700, 700),      # one row at its first position
+    (700, 700, 1024, 700),   # one row at the cache's last position
+], ids=["equal", "distinct", "one-at-1", "one-at-full"])
+def test_decode_attention_per_row_lengths(lengths):
+    """Each row masks at its own position (`max_pos [B]`), as the batched
+    decode wave does with slots at different positions."""
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    b, h, kh, s, hd = 2, 4, 2, 1024, 64
+    b, h, kh, s, hd = len(lengths), 4, 2, 1024, 64
     q = rand(ks[0], (b, h, hd), jnp.float32)
     k = rand(ks[1], (b, kh, s, hd), jnp.float32)
     v = rand(ks[2], (b, kh, s, hd), jnp.float32)
-    got = flash_decode_pallas(q, k, v, jnp.int32(700), block_s=block_s,
-                              interpret=True)
-    want = ref.flash_decode_ref(q, k, v, 700)
+    length = jnp.asarray(lengths, jnp.int32)
+    got = decode_attention(q, k, v, length - 1)
+    want = ref.decode_attention_ref(q, k, v, length)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5,
                                rtol=3e-5)
 
 
-def test_flash_decode_softmax_invariances():
-    """Scale-shift invariance: adding a constant to all K projections along
-    q direction shifts logits uniformly -> output unchanged; and output is
-    a convex combination of V rows (within their min/max envelope)."""
+def test_decode_attention_convex_combination():
+    """The output is a convex combination of V rows: within their min/max
+    envelope."""
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     b, h, kh, s, hd = 1, 2, 1, 256, 16
     q = rand(ks[0], (b, h, hd), jnp.float32)
     k = rand(ks[1], (b, kh, s, hd), jnp.float32)
     v = rand(ks[2], (b, kh, s, hd), jnp.float32)
-    out = flash_decode_pallas(q, k, v, jnp.int32(s), interpret=True)
+    out = decode_attention(q, k, v, jnp.int32(s - 1))
     vmin = np.asarray(v.min(axis=2))[:, :, None]
     vmax = np.asarray(v.max(axis=2))[:, :, None]
     o = np.asarray(out).reshape(b, kh, -1, hd)

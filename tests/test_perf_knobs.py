@@ -1,4 +1,4 @@
-"""Numerics of the §Perf optimization knobs: each must preserve model
+"""Numerics of the plan's optimization knobs: each must preserve model
 outputs within quantization/bf16 tolerance vs the paper-faithful baseline.
 Subprocess-based (needs 8 forced host devices)."""
 import json

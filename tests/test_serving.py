@@ -1,5 +1,7 @@
-"""Serving runtime tests: engine continuous batching, DBO step equivalence,
-and the speculative-decoding greedy-equivalence property."""
+"""Serving runtime tests: the continuous-batching engine against plain
+greedy decoding, over attention, MoE, sliding-window and recurrent
+(mamba, rwkv) layers."""
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -8,14 +10,13 @@ import pytest
 from repro.configs import get_arch, reduced_config
 from repro.models import model as M
 from repro.serving import kvcache
-from repro.serving.dbo import dbo_decode_step
 from repro.serving.engine import Engine
-from repro.serving.specdec import SDDecoder
 from repro.sharding.dist import NullDist
 from repro.sharding.plans import null_plan
 
 ARCHS_FAST = ["starcoder2-3b", "olmoe-1b-7b"]
 ARCHS_STATEFUL = ["rwkv6-1.6b", "jamba-v0.1-52b", "gemma3-1b"]
+ARCHS = ARCHS_FAST + ARCHS_STATEFUL
 
 
 def make_model(arch, seed=0):
@@ -24,17 +25,29 @@ def make_model(arch, seed=0):
     return cfg, params
 
 
-def greedy_reference(cfg, params, prompt, n_tokens, max_seq):
-    """Plain sequential greedy decode (the oracle for SD equivalence)."""
+@functools.cache
+def _greedy_steps(cfg):
+    """Jitted batch-1 prefill and decode step for `cfg` (one compile per
+    prompt length and one for the step, shared by every request)."""
     plan, dist = null_plan("decode"), NullDist()
     pplan = null_plan("prefill")
-    tok, caches = M.prefill(params, {"tokens": prompt}, cfg, pplan, dist)
+    prefill = jax.jit(lambda p, t: M.prefill(p, {"tokens": t}, cfg, pplan,
+                                             dist))
+    step = jax.jit(lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg,
+                                                      plan, dist))
+    return prefill, step
+
+
+def greedy_reference(cfg, params, prompt, n_tokens, max_seq):
+    """Plain sequential greedy decode, one request at a batch of one with a
+    scalar position (the oracle for the engine)."""
+    prefill, step = _greedy_steps(cfg)
+    tok, caches = prefill(params, prompt)
     caches = kvcache.pad_to_capacity(cfg, caches, prompt.shape[1], max_seq)
     toks = [tok]
     pos = prompt.shape[1]
     for i in range(n_tokens - 1):
-        tok, caches = M.decode_step(params, caches, tok, jnp.int32(pos),
-                                    cfg, plan, dist)
+        tok, caches = step(params, caches, tok, jnp.int32(pos))
         toks.append(tok)
         pos += 1
     return jnp.concatenate(toks, axis=1)
@@ -44,7 +57,7 @@ def greedy_reference(cfg, params, prompt, n_tokens, max_seq):
 # engine
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ARCHS_FAST + ["rwkv6-1.6b"])
+@pytest.mark.parametrize("arch", ARCHS)
 def test_engine_matches_sequential(arch):
     """Engine output for a single request == plain greedy decode."""
     cfg, params = make_model(arch)
@@ -57,9 +70,10 @@ def test_engine_matches_sequential(arch):
     assert out[rid][:6] == [int(t) for t in ref[0][:6]]
 
 
-def test_engine_continuous_batching():
+@pytest.mark.parametrize("arch", ARCHS)
+def test_engine_continuous_batching(arch):
     """More requests than slots: all complete, slots are reused."""
-    cfg, params = make_model("starcoder2-3b")
+    cfg, params = make_model(arch)
     eng = Engine(cfg, params, max_batch=2, max_seq=48, eos_id=-1)
     rids = [eng.submit([1 + i, 2 + i, 3 + i], max_new_tokens=4)
             for i in range(5)]
@@ -69,10 +83,11 @@ def test_engine_continuous_batching():
         assert len(out[r]) == 5        # 1 prefill token + 4 decode tokens
 
 
-def test_engine_isolation():
+@pytest.mark.parametrize("arch", ARCHS)
+def test_engine_isolation(arch):
     """Requests decoded together must not affect each other: run the same
     prompt alone and next to a different prompt."""
-    cfg, params = make_model("olmoe-1b-7b")
+    cfg, params = make_model(arch)
     p1, p2 = [3, 1, 4, 1, 5], [9, 2, 6, 5, 3]
     eng1 = Engine(cfg, params, max_batch=2, max_seq=48, eos_id=-1)
     r1 = eng1.submit(p1, max_new_tokens=5)
@@ -90,11 +105,11 @@ REFILL_REQUESTS = [([3, 1, 4, 1, 5], 2), ([9, 2, 6], 4),
 REFILL_MAX_SEQ = 10
 
 
-@pytest.fixture(scope="module")
-def refill_reference():
-    """olmoe (reduced) and each of REFILL_REQUESTS's greedy tokens, ended
-    by its budget or at the last position of the cache."""
-    cfg, params = make_model("olmoe-1b-7b")
+@functools.cache
+def refill_reference(arch):
+    """The model (reduced) and each of REFILL_REQUESTS's greedy tokens,
+    ended by its budget or at the last position of the cache."""
+    cfg, params = make_model(arch)
     refs = []
     for prompt, budget in REFILL_REQUESTS:
         n = 1 + min(budget, REFILL_MAX_SEQ - 1 - len(prompt))
@@ -104,15 +119,22 @@ def refill_reference():
     return cfg, params, refs
 
 
-@pytest.mark.parametrize("max_batch", [1, 2, 4])
-def test_engine_refills_slots_with_host_positions(refill_reference,
-                                                  max_batch):
+@pytest.mark.parametrize("arch,max_batch", [
+    pytest.param("olmoe-1b-7b", 1, id="1"),
+    pytest.param("olmoe-1b-7b", 2, id="2"),
+    pytest.param("olmoe-1b-7b", 4, id="4"),
+    ("rwkv6-1.6b", 2),
+    ("jamba-v0.1-52b", 2),
+])
+def test_engine_refills_slots_with_host_positions(arch, max_batch):
     """More requests than slots, with mixed budgets and two that run to
     the end of the cache: slots refill mid-run, every request is served
     the greedy reference's tokens and ends where it does, and each live
     slot's host-held position is its prompt length plus its decoded
-    tokens."""
-    cfg, params, refs = refill_reference
+    tokens. In the recurrent models (rwkv, mamba) a refilled slot must
+    start from its new prompt's state, not the state its last request
+    left behind."""
+    cfg, params, refs = refill_reference(arch)
     eng = Engine(cfg, params, max_batch=max_batch, max_seq=REFILL_MAX_SEQ,
                  eos_id=-1)
     rids = [eng.submit(p, max_new_tokens=b) for p, b in REFILL_REQUESTS]
@@ -125,115 +147,6 @@ def test_engine_refills_slots_with_host_positions(refill_reference,
     assert set(eng.finished) == set(rids)
     for rid, ref in zip(rids, refs):
         assert eng.finished[rid].generated == ref
-
-
-# ---------------------------------------------------------------------------
-# DBO step
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "jamba-v0.1-52b"])
-def test_dbo_step_equivalent_to_plain(arch):
-    """The interleaved DBO step must produce the same tokens/caches as two
-    independent plain decode steps (it only re-orders independent work)."""
-    cfg, params = make_model(arch)
-    plan, dist = null_plan("decode"), NullDist()
-    B, S = 2, 32
-    caches_a, _ = M.init_cache(cfg, plan, B, S)
-    caches_b, _ = M.init_cache(cfg, plan, B, S)
-    ta = jnp.array([[3], [5]], jnp.int32)
-    tb = jnp.array([[7], [9]], jnp.int32)
-    pos = jnp.int32(0)
-
-    na, ca, _ = *M.decode_step(params, caches_a, ta, pos, cfg, plan, dist), None
-    nb, cb, _ = *M.decode_step(params, caches_b, tb, pos, cfg, plan, dist), None
-    da, db, dca, dcb = dbo_decode_step(params, caches_a, caches_b, ta, tb,
-                                       pos, cfg, plan, dist)
-    assert (da == na).all() and (db == nb).all()
-    for x, y in zip(jax.tree.leaves(dca), jax.tree.leaves(ca)):
-        assert jnp.allclose(x, y, atol=1e-5), "microbatch A cache diverged"
-    for x, y in zip(jax.tree.leaves(dcb), jax.tree.leaves(cb)):
-        assert jnp.allclose(x, y, atol=1e-5), "microbatch B cache diverged"
-
-
-# ---------------------------------------------------------------------------
-# speculative decoding: THE invariant — SD == greedy, any draft
-# ---------------------------------------------------------------------------
-
-def _sd_vs_greedy(arch, draft_fn, n_tokens=8, seed=0):
-    cfg, params = make_model(arch, seed)
-    plan, dist = null_plan("decode"), NullDist()
-    max_seq = 64
-    prompt = jnp.asarray([[3, 5, 7, 11, 2, 4]], jnp.int32)
-    ref = greedy_reference(cfg, params, prompt, n_tokens, max_seq)
-
-    tok, caches = M.prefill(params, {"tokens": prompt}, cfg,
-                            null_plan("prefill"), dist)
-    caches = kvcache.pad_to_capacity(cfg, caches, prompt.shape[1], max_seq)
-    dec = SDDecoder(cfg, params, spec_m=4, draft_fn=draft_fn)
-    toks, _, stats = dec.generate(caches, tok, prompt.shape[1], n_tokens - 1)
-    got = jnp.concatenate([tok, toks], axis=1)
-    assert (got[:, :n_tokens] == ref).all(), (
-        f"{arch}: SD diverged from greedy: {got} vs {ref}")
-    return stats
-
-
-def bad_draft(params, caches, cur_tok, pos):
-    """Adversarial draft: constant garbage -> acceptance must just be 1."""
-    return jnp.full((cur_tok.shape[0], 3), 12345 % 500, jnp.int32)
-
-
-@pytest.mark.parametrize("arch", ARCHS_FAST + ARCHS_STATEFUL)
-def test_sd_equals_greedy_bad_draft(arch):
-    stats = _sd_vs_greedy(arch, bad_draft)
-    assert stats["mean_accepted"] >= 1.0
-
-
-@pytest.mark.parametrize("arch", ARCHS_FAST + ARCHS_STATEFUL)
-def test_sd_equals_greedy_medusa_heads(arch):
-    """Untrained Medusa heads (arbitrary draft quality) — output must STILL
-    equal greedy; this exercises partial-acceptance rollback paths."""
-    _sd_vs_greedy(arch, None)
-
-
-def test_sd_perfect_draft_accepts_all():
-    """Oracle draft (the model's own continuation) -> every iteration
-    accepts spec_m tokens."""
-    arch = "starcoder2-3b"
-    cfg, params = make_model(arch)
-    plan, dist = null_plan("decode"), NullDist()
-    max_seq = 64
-    prompt = jnp.asarray([[3, 5, 7, 11, 2, 4]], jnp.int32)
-    n_tokens = 9
-    ref = greedy_reference(cfg, params, prompt, n_tokens + 1, max_seq)
-
-    # oracle: look up the reference continuation by position
-    def oracle(params_, caches_, cur_tok, pos):
-        del params_, caches_
-        # cur_tok is ref[pos - prompt_len]; draft the next 3
-        i = pos - prompt.shape[1]
-        return jax.lax.dynamic_slice(ref, (0, i + 1), (1, 3))
-
-    # oracle needs concrete pos: drive manually
-    tok, caches = M.prefill(params, {"tokens": prompt}, cfg,
-                            null_plan("prefill"), dist)
-    caches = kvcache.pad_to_capacity(cfg, caches, prompt.shape[1], max_seq)
-    dec = SDDecoder(cfg, params, spec_m=4)
-    pos = prompt.shape[1]
-    got = [tok]
-    n_acc_all = []
-    cur = tok
-    while sum(t.shape[1] for t in got) < n_tokens:
-        d = oracle(None, None, cur, pos)
-        toks, n_acc, caches = dec._step(params, caches, cur, d,
-                                        jnp.int32(pos))
-        k = int(n_acc[0])
-        n_acc_all.append(k)
-        got.append(toks[:, :k])
-        cur = toks[:, k - 1:k]
-        pos += k
-    seq = jnp.concatenate(got, axis=1)[:, :n_tokens]
-    assert (seq == ref[:, :n_tokens]).all()
-    assert all(k == 4 for k in n_acc_all[:-1]), n_acc_all
 
 
 # ---------------------------------------------------------------------------

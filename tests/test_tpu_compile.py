@@ -1,11 +1,12 @@
-"""Compile-only checks of the Pallas kernels for a described TPU v5e.
+"""Compile-only checks of the Pallas kernels and the decode path for a
+described TPU v5e.
 
 The TPU compiler compiles for a chip that is described, not attached, so
 these tests run on a CPU-only host: nothing executes, but the compiler
 refuses what the chip would refuse (VMEM over the scoped limit, tiles not
-aligned to the hardware). Each test asserts that the compiled program
-still holds the Mosaic kernel (`tpu_custom_call`), i.e. that nothing fell
-back to plain XLA.
+aligned to the hardware). Each kernel test asserts that the compiled
+program still holds the Mosaic kernel (`tpu_custom_call`), i.e. that
+nothing fell back to plain XLA.
 
 The topology is described inside a fixture and never while a module is
 imported: only one process at a time may load the TPU library, and the
@@ -20,7 +21,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
-from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.models.layers.moe import capacity
 
@@ -104,13 +104,33 @@ def test_decode_wave_calls_moe_gmm_once_over_all_slots(arch, one_chip,
     assert kernels and all(x_e in line for line in kernels), kernels
 
 
-def test_flash_decode_compiles_at_olmoe_width(one_chip):
-    cfg = get_arch("olmoe-1b-7b")
-    b, h, kh, hd, s = (DECODE_SLOTS, cfg.num_heads, cfg.num_kv_heads,
-                       cfg.head_dim, 512)
-    compiled = jax.jit(flash_decode_pallas).lower(
-        _struct((b, h, hd), one_chip),
-        _struct((b, kh, s, hd), one_chip),
-        _struct((b, kh, s, hd), one_chip),
-        _struct((), one_chip, jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_attention_decode_compiles_at_granite_width(one_chip):
+    """The decode wave's attention at granite-moe-3b-a800m's width: 24
+    heads over 8 KV heads of 64, a cache of 32 slots x 1024 positions,
+    each slot at its own position. The cache is donated, and the compiled
+    program writes the new K/V into it in place."""
+    from repro.models.layers.attention import attention_decode, \
+        init_attention
+    from repro.sharding.dist import NullDist
+    from repro.sharding.plans import null_plan
+
+    cfg = get_arch("granite-moe-3b-a800m")
+    slots, seq = 32, 1024
+    plan = null_plan("decode")
+    params = jax.eval_shape(lambda k: init_attention(cfg, plan, k)[0],
+                            jax.random.PRNGKey(0))
+    kv = (slots, cfg.num_kv_heads, seq, cfg.head_dim)
+    assert kv == (32, 8, 1024, 64) and cfg.num_heads == 24
+
+    def step(params, x, cache, pos):
+        return attention_decode(params, x, cache, pos, cfg, plan, NullDist())
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        jax.tree.map(lambda a: _struct(a.shape, one_chip, a.dtype), params),
+        _struct((slots, 1, cfg.d_model), one_chip),
+        {"k": _struct(kv, one_chip), "v": _struct(kv, one_chip)},
+        _struct((slots,), one_chip, jnp.int32)).compile()
+    y, cache = compiled.out_info
+    assert y.shape == (slots, 1, cfg.d_model)
+    assert cache["k"].shape == kv and cache["v"].shape == kv
+    assert "input_output_alias" in compiled.as_text()
